@@ -1,5 +1,7 @@
 import os
+# a host-side lowering tool: 512 placeholder CPU devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """§Perf hillclimb driver: re-lower the three chosen cells under each
 candidate change and append labeled records to dryrun_results.jsonl.

@@ -17,6 +17,7 @@ import time
 
 from repro import Scenario
 from repro.configs import SHAPES, get
+from repro.parallel.sharding import shard_plan
 
 COLL_MAP = {"all-gather": "AllGather", "all-reduce": "AllReduce",
             "reduce-scatter": "ReduceScatter", "all-to-all": "AllToAll"}
@@ -25,10 +26,7 @@ COLL_MAP = {"all-gather": "AllGather", "all-reduce": "AllReduce",
 def _scenario(arch, mesh_tag: str) -> Scenario:
     multi = mesh_tag.startswith("2x")
     spec = arch.spec
-    kv_ok = spec.n_kv_heads % 16 == 0 and spec.block != "mla"
-    grp_ok = (max(1, spec.n_heads // max(1, spec.n_kv_heads)) % 16 == 0)
-    fsdp = (spec.moe is not None) or not (kv_ok or grp_ok
-                                          or spec.block in ("mla", "rwkv6"))
+    _, fsdp = shard_plan(spec, 16)        # the production mesh's model axis
     # MoE archs route experts over the tensor axis here, mirroring the
     # runtime's shard_map EP path on the production mesh's model axis
     return Scenario(spec).parallel(dp=32 if multi else 16, tp=16, sp=True,

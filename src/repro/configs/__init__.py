@@ -1,1 +1,1 @@
-from .base import ARCHS, SHAPES, Arch, ShapeSpec, all_archs, get
+from .base import ARCHS, SHAPES, Arch, ShapeSpec, all_archs, cut_depth, get

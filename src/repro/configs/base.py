@@ -71,3 +71,22 @@ def get(name: str) -> Arch:
 
 def all_archs():
     return [get(a) for a in ARCHS]
+
+
+def cut_depth(spec: ModelSpec, n_layers: int) -> ModelSpec:
+    """``spec`` at its published widths with ``n_layers`` decoder layers.
+
+    Only whole periods of the layer pattern (after any unstacked prefix)
+    are kept, so the cut model runs the same mix of layer kinds; any
+    other depth raises ``ValueError``."""
+    from repro.models.lm import layer_pattern
+    prefix, period = layer_pattern(spec)
+    if not (prefix < n_layers <= spec.n_layers
+            and (n_layers - prefix) % period == 0):
+        raise ValueError(
+            f"{spec.name}: --layers {n_layers} must be {prefix} prefix "
+            f"layer(s) plus whole periods of {period}, at most "
+            f"{spec.n_layers}")
+    cut = dataclasses.replace(spec, n_layers=n_layers)
+    assert layer_pattern(cut) == (prefix, period), spec.name
+    return cut

@@ -22,9 +22,9 @@ memory for the batch with one ``jit``-compiled kernel:
   arithmetic, no ``pow``).  The byte-access / memory-event selection
   tables are ~99% zeros, so they ship as COO triplets and reduce via
   ``segment_sum``; the dense busy-group contraction
-  (``[B, entries] x [groups, entries]``) stays on the Pallas reduction
-  kernel (:func:`repro.kernels.ops.cost_reduce` — MXU-tiled on TPU,
-  exact float64 jnp contraction as the CPU/CI reference).
+  (``[B, entries] x [groups, entries]``) is a float64 XLA dot on every
+  backend (the float32-accumulating Pallas ``cost_reduce`` kernel is
+  kept off this path: float32 breaks the parity budget).
 * **Two-stream scheduling** — the reference ``simulate._schedule`` list
   scheduler becomes one ``lax.scan`` over the flattened slot-group
   sequence: dependencies resolve positionally *within* a group (each
@@ -49,11 +49,12 @@ microbatch-independent; ``step = mb * span + opt``), so one kernel
 covers the mb dimension of a sweep; pipelined groups key on
 (schedule, mb) because the replay plan depends on both.
 
-Numerics: results must match the compiled backend within rel 1e-6 on
-CPU, which requires float64 — constructing a :class:`BatchedBackend`
-enables ``jax_enable_x64`` (guarded; see ``_ensure_x64``).  The
-``dtype`` hook exists so the regression test can demonstrate float32 is
-NOT sufficient.
+Numerics: results must match the compiled backend within rel 1e-6,
+which requires float64.  The kernels build their constants, trace and
+dispatch inside a scoped ``jax.enable_x64`` (see ``_x64``), so the
+process-wide default dtypes that the model runtime sees never change.
+The ``dtype`` hook exists so the regression test can demonstrate
+float32 is NOT sufficient.
 """
 from __future__ import annotations
 
@@ -80,13 +81,15 @@ _log = get_logger("core.batched")
 # schedules whose replay order is duration-independent (zb-h1 backfills
 # weight-grad slots into gaps whose existence depends on the durations)
 REPLAYABLE_SCHEDULES = ("gpipe", "1f1b", "interleaved")
+MIN_ROWS = 128          # fewest config rows a kernel call is padded to
 
 
-def _ensure_x64() -> None:
-    """The 1e-6 parity budget needs float64; jax defaults to 32."""
+def _x64():
+    """Scope for every jax call of this module: the 1e-6 parity budget
+    needs float64, and jax defaults to 32.  Thread-local, and undone on
+    exit, so no other jax code in the process sees 64-bit defaults."""
     import jax
-    if not jax.config.jax_enable_x64:
-        jax.config.update("jax_enable_x64", True)
+    return jax.enable_x64(True)
 
 
 def _next_pow2(n: int) -> int:
@@ -147,7 +150,7 @@ def _subset_products(jnp, degs):
 
 def _seg_reduce(x, coo, nseg: int):
     """``out[b, r] = sum_nz vals[nz] * x[b, cols[nz]]`` over a COO
-    table — the sparse counterpart of :func:`ops.cost_reduce` for the
+    table — the sparse counterpart of the dense busy-group dot for the
     ~99%-sparse byte-access / memory-event selection tables, O(B*nnz)
     instead of the dense O(B*R*T)."""
     import jax
@@ -172,7 +175,12 @@ class _ClassKernel:
     def __init__(self, prog: CostProgram, axes: tuple, pp: int, vstages: int,
                  schedule: str, microbatches: int, recompute: bool,
                  dtype=None):
-        _ensure_x64()
+        with _x64():
+            self._build(prog, axes, pp, vstages, schedule, microbatches,
+                        recompute, dtype)
+
+    def _build(self, prog, axes, pp, vstages, schedule, microbatches,
+               recompute, dtype):
         import jax
         import jax.numpy as jnp
         self._jnp = jnp
@@ -308,8 +316,8 @@ class _ClassKernel:
         for i, ds in enumerate(seq_deps):
             deps[i, :len(ds)] = ds
         is_comm = np.asarray([entries[k][11] is not None for k in seq_entry])
-        m_comp = np.zeros((G, K), np.float32)
-        m_comm = np.zeros((G, K), np.float32)
+        m_comp = np.zeros((G, K))
+        m_comm = np.zeros((G, K))
         for i, (k, g) in enumerate(zip(seq_entry, seq_group)):
             (m_comm if is_comm[i] else m_comp)[g, i] = 1.0
 
@@ -437,7 +445,7 @@ class _ClassKernel:
             "seq_is_comm": jnp.asarray(is_comm),
             "deps": jnp.asarray(deps),
             "glast": jnp.asarray(glast),
-            "m_comp": jnp.asarray(m_comp), "m_comm": jnp.asarray(m_comm),
+            "m_comp": f(m_comp.T), "m_comm": f(m_comm.T),
             "s_w": jnp.asarray(s_w), "u_m": f(u_m), "u_g": f(u_g),
             "s_mem": coo(s_mem), "s_layer": coo(s_layer),
         }
@@ -470,7 +478,6 @@ class _ClassKernel:
     # ---- the jitted batch evaluator --------------------------------------
     def _eval(self, degs, mbs, eff_e, bw_e, peak, hbm, lat):
         import jax
-        from ..kernels.ops import cost_reduce
         jnp = self._jnp
         c = self._c
         B = degs.shape[0]
@@ -532,8 +539,8 @@ class _ClassKernel:
         live = c["glast"] >= 0
         spans = jnp.where(live[:, None],
                           frees[jnp.maximum(c["glast"], 0)], 0.0)  # [G, B]
-        busy_c = cost_reduce(dur_bk, c["m_comp"])           # [B, G]
-        busy_m = cost_reduce(dur_bk, c["m_comm"])
+        busy_c = dur_bk @ c["m_comp"]                       # [B, G]
+        busy_m = dur_bk @ c["m_comm"]
 
         if self.pp <= 1:
             gm, go = self._g_mb, self._g_opt
@@ -615,10 +622,11 @@ class _ClassKernel:
         """Dispatch the jitted kernel; values are async jax arrays —
         converting with ``np.asarray`` waits for them."""
         jnp = self._jnp
-        eff_e, bw_e, peak, hbm, lat = self._hw_arrays(hw)
         dt = self.dtype
-        return self._fn(jnp.asarray(degs, dt), jnp.asarray(mbs, dt),
-                        eff_e, bw_e, peak, hbm, lat)
+        with _x64():
+            eff_e, bw_e, peak, hbm, lat = self._hw_arrays(hw)
+            return self._fn(jnp.asarray(degs, dt), jnp.asarray(mbs, dt),
+                            eff_e, bw_e, peak, hbm, lat)
 
     def run(self, degs: np.ndarray, mbs: np.ndarray, hw) -> dict:
         out = self.run_async(degs, mbs, hw)
@@ -634,7 +642,6 @@ class BatchedBackend:
     demonstrably breaks the 1e-6 parity budget; leave as None)."""
 
     def __init__(self, engine: CompiledBackend, *, dtype=None):
-        _ensure_x64()
         self.engine = engine
         self.dtype = dtype
         self._kernels: dict = {}
@@ -729,7 +736,10 @@ class BatchedBackend:
     def _dispatch(self, kern: _ClassKernel, cfgs: list, idxs: list, hw
                   ) -> dict:
         B = len(idxs)
-        Bp = _next_pow2(B)                      # pow2 pad bounds retraces
+        # pow2 pad bounds retraces; at least one 128-lane tile, since the
+        # TPU compiler takes ~3 min per kernel on 32 or 64 rows and ~4 s
+        # on 128 (v5e, GPT3-5B classes)
+        Bp = max(MIN_ROWS, _next_pow2(B))
         degs = np.ones((Bp, len(kern.axes)))
         mbs = np.ones(Bp)
         for j, i in enumerate(idxs):

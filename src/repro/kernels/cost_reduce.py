@@ -11,7 +11,9 @@ grid dimensions, the tensor axis is the innermost sequential one with a
 
 On CPU/CI the interpreter mode of this same kernel is the reference
 (tests pin it against the jnp dot); the public wrapper in ops.py picks
-the compiled kernel only on TPU.
+the compiled kernel only on TPU.  The batched evaluator itself does not
+call it: it accumulates in float32, and the evaluator's 1e-6 parity
+budget needs float64.
 """
 from __future__ import annotations
 
@@ -21,10 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 
 def _cost_reduce_kernel(x_ref, w_ref, o_ref):
@@ -71,7 +69,7 @@ def cost_reduce_bet(x: jax.Array, w: jax.Array, *, block_b: int = 128,
                   pl.BlockSpec((block_e, block_t), lambda i, j, k: (j, k))],
         out_specs=pl.BlockSpec((block_b, block_e), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, ep), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xf, wf)

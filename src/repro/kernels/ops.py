@@ -25,15 +25,14 @@ def _on_tpu() -> bool:
 def cost_reduce(x, w, *, interpret: Optional[bool] = None) -> jax.Array:
     """Batched cost reduction ``out[b, e] = sum_t x[b, t] * w[e, t]``.
 
-    The dense contraction of the batched DSE backend: x [B, K] per-slot
-    durations, w [G, K] static busy-group membership rows (the sparse
-    byte-access / memory-event selections go through ``segment_sum``
-    COO reductions instead).  On TPU the Pallas MXU kernel runs compiled
-    (float32 accumulation); elsewhere the jnp reference contraction runs
-    in the input dtype — float64 under x64, which is what the batched
-    backend's 1e-6 CPU parity budget relies on.  ``interpret=True``
-    forces the Pallas kernel through the interpreter (CI correctness
-    tests for the kernel itself)."""
+    Shaped for the batched DSE backend's busy-group contraction: x [B, K]
+    per-slot durations, w [G, K] static busy-group membership rows.  That
+    backend does not call it: its contraction is a float64 XLA dot,
+    because this kernel accumulates in float32 and misses the backend's
+    1e-6 parity budget.  On TPU the Pallas MXU kernel runs compiled;
+    elsewhere the jnp reference contraction runs in the input dtype.
+    ``interpret=True`` forces the Pallas kernel through the interpreter
+    (CI correctness tests for the kernel itself)."""
     if interpret is None:
         if not _on_tpu():
             return x @ w.T.astype(x.dtype)

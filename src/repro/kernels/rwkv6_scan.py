@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sout_ref,
                 state_scr, *, chunk: int):
@@ -44,7 +40,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sout_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)                      # [D]
+    u = u_ref[0].astype(jnp.float32)                      # [1, D]
 
     lw = jnp.log(jnp.maximum(w, 1e-30))
     ii = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
@@ -63,7 +59,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sout_ref,
     s = jax.lax.dot_general(rt, kt, (((1,), (1,)), ((), ())))   # [C, C]
     s = jnp.where(jj < ii, s, 0.0)
     intra = jax.lax.dot_general(s, v, (((1,), (0,)), ((), ())))
-    coef = jnp.sum(r * u[None] * k, axis=1, keepdims=True)
+    coef = jnp.sum(r * u * k, axis=1, keepdims=True)
     out = inter + intra + coef * v
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
@@ -104,7 +100,9 @@ def wkv6_bhsd(r, k, v, w, u, state0, *, chunk: int = 64,
             pl.BlockSpec((1, 1, chunk, dd), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, dd), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, chunk, dd), lambda b_, h_, c: (b_, h_, c, 0)),
-            pl.BlockSpec((1, dd), lambda b_, h_, c: (h_, 0)),
+            # u as [H, 1, dd]: the last two block dims then equal the
+            # array's, as the TPU tiling rule requires
+            pl.BlockSpec((1, 1, dd), lambda b_, h_, c: (h_, 0, 0)),
             pl.BlockSpec((1, 1, dd, dd), lambda b_, h_, c: (b_, h_, 0, 0)),
         ],
         out_specs=[
@@ -116,8 +114,8 @@ def wkv6_bhsd(r, k, v, w, u, state0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((b, h, dd, dd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dd, dd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u, state0)
+    )(r, k, v, w, u.reshape(h, 1, dd), state0)
     return out[..., :d], sout[..., :d, :d]

@@ -1,6 +1,8 @@
 import os
+# a host-side lowering tool: 512 placeholder CPU devices, never the chip
 os.environ["XLA_FLAGS"] = (os.environ.get("_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production mesh, record memory/cost/collective analysis.
@@ -23,13 +25,13 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, Arch, get as get_arch, ARCHS
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import data_axes_of, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.preflight import preflight
 from repro.models import lm
-from repro.models.common import AxisRules, Param, RuntimeCfg
-from repro.parallel.sharding import (logical_rules, param_pspec,
-                                     param_shardings)
+from repro.models.common import AxisRules, RuntimeCfg
+from repro.parallel.sharding import arch_rules, data_axes_of, param_shardings
 from repro.train.optimizer import (OptCfg, init_opt_state,
                                    opt_state_shardings)
 from repro.train.train_step import make_train_step
@@ -38,26 +40,6 @@ from repro.train.train_step import make_train_step
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 LINK_BW = 50e9
-
-
-def arch_rules(arch: Arch, mesh, *, overrides: Optional[dict] = None,
-               sp: Optional[bool] = None) -> dict:
-    """Per-arch logical->mesh rules with divisibility-driven choices."""
-    spec = arch.spec
-    model = mesh.shape["model"]
-    kv_ok = spec.n_kv_heads % model == 0 and spec.block not in ("mla",)
-    grp_ok = (max(1, spec.n_heads // max(1, spec.n_kv_heads)) % model == 0)
-    # FSDP(ZeRO-3) weights over data when attention is unshardable over
-    # model (qwen3/minitron/internvl) or the model is MoE (expert weights
-    # would otherwise replicate across the data axes).
-    fsdp = (spec.moe is not None) or \
-        not (kv_ok or grp_ok or spec.block in ("mla", "rwkv6"))
-    rules = logical_rules(
-        sp=arch.runtime.sp if sp is None else sp, fsdp=fsdp,
-        shard_kv_heads=kv_ok,
-        data_axes=data_axes_of(mesh),
-        extra=overrides)
-    return rules
 
 
 def abstract_params(arch: Arch, rt: RuntimeCfg):
@@ -141,7 +123,7 @@ def lower_cell(arch: Arch, shape_name: str, *, multi_pod: bool = False,
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     rt = rt or RuntimeCfg(remat="full")
-    rules_d = arch_rules(arch, mesh, overrides=rule_overrides, sp=rt.sp)
+    rules_d = arch_rules(arch.spec, mesh, overrides=rule_overrides, sp=rt.sp)
     rules = AxisRules(rules_d)
     rules.mesh = mesh            # enables the shard_map EP path in MoE
     spec = arch.spec
@@ -321,6 +303,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default="dryrun_results.jsonl")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.all:
         done = done_cells(args.out)

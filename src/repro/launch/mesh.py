@@ -1,8 +1,10 @@
-"""Production mesh builders (assignment-prescribed shapes).
+"""Mesh builders.
 
-A function, not a module-level constant, so importing this module never
+Functions, not module-level constants, so importing this module never
 touches jax device state."""
 from __future__ import annotations
+
+import math
 
 import jax
 
@@ -15,5 +17,10 @@ def make_production_mesh(*, multi_pod: bool = False):
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def data_axes_of(mesh) -> tuple:
-    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+def make_local_mesh():
+    """(data, model) mesh over the local devices, as square as the device
+    count allows: 1 chip -> 1x1, 4 chips -> 2x2, 8 -> 4x2."""
+    n = jax.device_count()
+    model = max(d for d in range(1, math.isqrt(n) + 1) if n % d == 0)
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -52,6 +52,32 @@ def axis_rules(mesh: Mesh, **kw) -> AxisRules:
     return AxisRules(logical_rules(**kw))
 
 
+def data_axes_of(mesh: Mesh) -> tuple:
+    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
+
+
+def shard_plan(spec, model: int) -> tuple[bool, bool]:
+    """``(shard_kv_heads, fsdp)`` for ``spec`` on a ``model``-way model
+    axis.  KV heads shard when they divide it (never for MLA).  FSDP
+    (ZeRO-3) weights over data when attention is unshardable over model
+    (neither kv heads nor query groups divide it: qwen3 / minitron /
+    internvl at 16) or the model is MoE (expert weights would otherwise
+    replicate across the data axes)."""
+    kv_ok = spec.n_kv_heads % model == 0 and spec.block != "mla"
+    grp_ok = max(1, spec.n_heads // max(1, spec.n_kv_heads)) % model == 0
+    fsdp = (spec.moe is not None) or \
+        not (kv_ok or grp_ok or spec.block in ("mla", "rwkv6"))
+    return kv_ok, fsdp
+
+
+def arch_rules(spec, mesh: Mesh, *, sp: bool = True,
+               overrides: Optional[dict] = None) -> dict:
+    """Per-arch logical->mesh rules with divisibility-driven choices."""
+    kv_ok, fsdp = shard_plan(spec, mesh.shape["model"])
+    return logical_rules(sp=sp, fsdp=fsdp, shard_kv_heads=kv_ok,
+                         data_axes=data_axes_of(mesh), extra=overrides)
+
+
 def _divisible(shape, axes_entry, mesh: Mesh, dim: int) -> bool:
     if axes_entry is None:
         return True

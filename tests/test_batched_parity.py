@@ -149,10 +149,20 @@ def test_batched_sweep_matches_compiled():
 
 
 def test_batched_backend_requires_x64():
-    """Constructing the backend flips the x64 switch (guarded)."""
+    """The backend evaluates in float64 inside its own scoped x64 switch
+    and leaves the process-wide default dtypes (what the model runtime
+    sees) untouched."""
     import jax
-    _scenario(get("qwen3-14b").smoke, "train")  # ensure jax imported
-    assert jax.config.jax_enable_x64
+    import jax.numpy as jnp
+    import numpy as np
+    sc = _scenario(get("qwen3-14b").smoke, "train")
+    backend = BatchedBackend(_engines.engine(sc.spec, sc.mode, sc.env()))
+    assert backend.evaluate_many(_cfgs(sc, sc.spec)[:1], TPU_V5E)[0]
+    kern = next(iter(backend._kernels.values()))
+    out = kern.run_async(np.ones((1, len(kern.axes))), np.ones(1), TPU_V5E)
+    assert out["step"].dtype == np.float64
+    assert not jax.config.jax_enable_x64
+    assert jnp.zeros(1).dtype == jnp.float32
 
 
 def _sim_rel_err(backend, sc):

@@ -169,13 +169,12 @@ def test_cost_reduce_auto_path_f64():
     """Off-TPU the auto path is the jnp contraction in the input dtype —
     float64 under x64, double-precision-close to the numpy product
     (1e-14 would fail by ~7 digits if the reduction ran in float32)."""
-    from repro.core.batched import _ensure_x64
-    _ensure_x64()
     rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.standard_normal((5, 37)))
-    w = jnp.asarray(rng.standard_normal((9, 37)))
-    assert x.dtype == jnp.float64
-    out = ops.cost_reduce(x, w)
+    with jax.enable_x64(True):
+        x = jnp.asarray(rng.standard_normal((5, 37)))
+        w = jnp.asarray(rng.standard_normal((9, 37)))
+        assert x.dtype == jnp.float64
+        out = ops.cost_reduce(x, w)
     assert out.dtype == jnp.float64
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(x) @ np.asarray(w).T,
