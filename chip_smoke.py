@@ -39,28 +39,21 @@ def check(ok: bool, what: str) -> None:
         raise SystemExit(f"chip_smoke: check failed: {what}")
 
 
-class CompileClock:
-    """Seconds jax spends in backend compilation (persistent-cache hits
-    skip it, so a warm second run shows less)."""
-
-    def __init__(self):
-        import jax
-        self.secs = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.secs += duration
+def compile_s() -> float:
+    """Seconds jax has spent in backend compilation so far (persistent-
+    cache hits skip it, so a warm second run shows less)."""
+    from repro.obs import metrics
+    return metrics.histogram("jit.compile_s").total
 
 
-def run_phase(name: str, fn, clock: CompileClock, dev) -> None:
+def run_phase(name: str, fn, dev) -> None:
     print(f"[chip_smoke] phase {name}", flush=True)
-    t0, c0 = time.perf_counter(), clock.secs
+    t0, c0 = time.perf_counter(), compile_s()
     fn()
     wall = time.perf_counter() - t0
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
     print(f"[chip_smoke phase timing] {name}: wall {wall:.1f} s, compile "
-          f"{clock.secs - c0:.1f} s, process peak_bytes_in_use {peak}",
+          f"{compile_s() - c0:.1f} s, process peak_bytes_in_use {peak}",
           flush=True)
 
 
@@ -242,13 +235,14 @@ def main() -> None:
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
     from repro.launch.compile_cache import use_compile_cache
     print(f"[chip_smoke] compile cache {use_compile_cache()}")
+    from repro.obs import runtime_hooks
+    runtime_hooks()                 # the jit.compile_s histogram
     OUT.mkdir(parents=True, exist_ok=True)
-    clock = CompileClock()
     phases = ([("train4", phase_train4)] if args.chips == 4 else
               [("serve", phase_serve), ("train", phase_train),
                ("sweep", phase_sweep)])
     for name, fn in phases:
-        run_phase(name, fn, clock, devs[0])
+        run_phase(name, fn, devs[0])
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

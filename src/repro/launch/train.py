@@ -12,9 +12,14 @@ under the same logical sharding rules the dry-run lowers (FSDP/ZeRO-1),
 and the step donates them.  Checkpoints are written to, and resumed
 from, ``--ckpt-dir`` only.  Wires together: config registry, data
 pipeline, sharded train_step, checkpoint manager, straggler watchdog.
+
+Each step is a ``train.step`` span (``repro.obs``) with the children
+``train.data`` (batch and ``device_put``), ``train.dispatch`` and
+``train.sync`` (the host's read of the loss); its duration feeds the
+log line and the watchdog, and under a JAX profiler the spans name the
+device's idle gaps.
 """
 import argparse
-import time
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -28,6 +33,7 @@ from repro.launch.mesh import make_local_mesh
 from repro.launch.preflight import announce, preflight
 from repro.models import RuntimeCfg, init_params
 from repro.models.common import AxisRules
+from repro.obs import span, timed
 from repro.parallel.sharding import arch_rules, data_axes_of, param_shardings
 from repro.train import OptCfg, init_opt_state, make_train_step
 from repro.train.optimizer import opt_state_shardings
@@ -109,14 +115,17 @@ def main(argv=None) -> dict:
 
         losses = []
         for step in range(start, args.steps):
-            t0 = time.time()
-            batch = {k: jax.device_put(v, b_shard)
-                     for k, v in pipe.batch(step).items()}
-            params, opt, m = step_fn(params, opt, batch)
-            loss = float(m["loss"])
-            d = watchdog.observe(time.time() - t0)
-            print(f"step {step:4d} loss {loss:.4f} "
-                  f"({time.time()-t0:.2f}s) [{d.kind}]", flush=True)
+            with timed("train.step", step=step) as t:
+                with span("train.data"):
+                    batch = {k: jax.device_put(v, b_shard)
+                             for k, v in pipe.batch(step).items()}
+                with span("train.dispatch"):
+                    params, opt, m = step_fn(params, opt, batch)
+                with span("train.sync"):
+                    loss = float(m["loss"])
+            d = watchdog.observe(t.dur)
+            print(f"step {step:4d} loss {loss:.4f} ({t.dur:.2f}s) "
+                  f"[{d.kind}]", flush=True)
             losses.append(loss)
             if mgr is not None:
                 mgr.maybe_save(step + 1, {"params": params, "opt": opt},

@@ -9,6 +9,11 @@ compiled program describe the same computation:
   (sub-quadratic memory; what the Pallas kernel computes on TPU).
 * RWKV6 / Mamba use chunked linear-recurrence scans carrying an O(1)
   state — memory O(B·C²) per chunk instead of O(B·S·D·D).
+* Each layer kind traces under a ``jax.named_scope`` of its function's
+  name (``gqa_attention``, ``mla_attention``, ``ffn``, ``moe_ffn``,
+  ``mamba_layer``, ``rwkv6_time_mix``, ``rwkv6_channel_mix``), so the
+  HLO ``op_name`` of each of its ops, and the profiler's device events,
+  say which layer they belong to.  Trace time only: no arithmetic moves.
 """
 from __future__ import annotations
 
@@ -31,6 +36,15 @@ BATCH, SEQ, KVSEQ = "act_batch", "act_seq", "act_kv"
 
 def cast(x, rt: RuntimeCfg):
     return x.astype(dt(rt.compute_dtype))
+
+
+def scoped(fn):
+    """Trace ``fn`` inside a ``jax.named_scope`` of its own name."""
+    @functools.wraps(fn)
+    def wrapper(*args, **kw):
+        with jax.named_scope(fn.__name__):
+            return fn(*args, **kw)
+    return wrapper
 
 
 # ---------------------------------------------------------------------------
@@ -208,6 +222,7 @@ def init_gqa(ini: Initializer, spec, prefix: str = "", cross: bool = False) -> d
     return p
 
 
+@scoped
 def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
                   window: Optional[int] = None, causal: bool = True,
@@ -297,6 +312,7 @@ def init_mla(ini: Initializer, spec, prefix: str = "") -> dict:
     }
 
 
+@scoped
 def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
                   cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
@@ -365,6 +381,7 @@ def init_ffn(ini: Initializer, spec, width: Optional[int] = None,
     return p
 
 
+@scoped
 def ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         rules: Optional[AxisRules]) -> jax.Array:
     h = rms_norm(p["ln"], x)
@@ -469,6 +486,7 @@ def _route_and_compute(h, wr, wg, wu, wd, *, E: int, Kk: int,
     return token_out.reshape(b, s, H)
 
 
+@scoped
 def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
             rules: Optional[AxisRules], *, capacity_factor: float = 0.0) -> jax.Array:
     """Sort-based top-k MoE with static expert capacity.
@@ -585,6 +603,7 @@ def _ssm_scan(dA: jax.Array, dBx: jax.Array, h0: jax.Array,
     return hs, h_last
 
 
+@scoped
 def mamba_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                 rules: Optional[AxisRules], *,
                 cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
@@ -702,9 +721,13 @@ def _wkv_chunk(r, k, v, w, u, state):
     return out, new_state
 
 
-def rwkv6_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
-                rules: Optional[AxisRules], *, chunk: int = 32,
-                cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+@scoped
+def rwkv6_time_mix(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
+                   rules: Optional[AxisRules], *, chunk: int,
+                   cache: Optional[dict]) -> tuple:
+    """Time mix: token shift, r/k/v/g and decay projections, the chunked
+    WKV scan, group norm and output.  Returns (x, WKV state after the
+    last token, the normed input ``h``)."""
     b, s, H = x.shape
     nh, dh = spec.n_heads, spec.head_dim
     h = rms_norm(p["ln"], x)
@@ -743,9 +766,15 @@ def rwkv6_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     out = rms_norm(p["gn"], out)                           # per-head groupnorm
     out = out * jax.nn.silu(g)
     tm = jnp.einsum("bsnd,ndh->bsh", out, cast(p["w_tmo"].value, rt))
-    x = x + constrain(tm, rules, (BATCH, SEQ, EMB))
+    return x + constrain(tm, rules, (BATCH, SEQ, EMB)), state_last, h
 
-    # channel mix
+
+@scoped
+def rwkv6_channel_mix(p: dict, x: jax.Array, rt: RuntimeCfg,
+                      rules: Optional[AxisRules], *,
+                      cache: Optional[dict]) -> tuple:
+    """Channel mix: token shift, squared-ReLU key, sigmoid receptance.
+    Returns (x, the normed input ``hc``)."""
     hc = rms_norm(p["ln_cm"], x)
     shifted_c = _token_shift(hc, cache["shift_cm"] if cache is not None else None)
     mk = hc + (shifted_c - hc) * cast(p["mu_ck"].value, rt)
@@ -754,8 +783,15 @@ def rwkv6_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     kk = jnp.square(jax.nn.relu(kk))
     vv = jnp.einsum("bsf,fh->bsh", kk, cast(p["w_cv"].value, rt))
     rr = jax.nn.sigmoid(jnp.einsum("bsh,hg->bsg", mr, cast(p["w_cr"].value, rt)))
-    x = x + constrain(vv * rr, rules, (BATCH, SEQ, EMB))
+    return x + constrain(vv * rr, rules, (BATCH, SEQ, EMB)), hc
 
+
+def rwkv6_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
+                rules: Optional[AxisRules], *, chunk: int = 32,
+                cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+    x, state_last, h = rwkv6_time_mix(p, x, spec, rt, rules, chunk=chunk,
+                                      cache=cache)
+    x, hc = rwkv6_channel_mix(p, x, rt, rules, cache=cache)
     new_cache = None
     if cache is not None:
         new_cache = {"wkv": state_last, "shift_tm": h[:, -1],

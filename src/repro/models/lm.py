@@ -212,8 +212,9 @@ def forward(params: dict, tokens, spec, rt: RuntimeCfg,
             rules: Optional[AxisRules] = None, *, frames=None,
             vision=None, positions=None) -> jax.Array:
     """Training / prefill forward -> logits [B, S(+Sv), V]."""
-    x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
-    x = L.constrain(x, rules, (L.BATCH, L.SEQ, L.EMB))
+    with jax.named_scope("embed"):
+        x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
+        x = L.constrain(x, rules, (L.BATCH, L.SEQ, L.EMB))
     if vision is not None:
         x = jnp.concatenate([vision.astype(x.dtype), x], axis=1)
     cross_kv = None
@@ -249,13 +250,14 @@ def forward(params: dict, tokens, spec, rt: RuntimeCfg,
             scanned = scanned + [params["cross"]]
         x, _ = jax.lax.scan(_remat(group, rt), x, tuple(scanned))
 
-    x = L.rms_norm(params["ln_f"], x)
-    x = L.constrain(x, rules, (L.BATCH, L.SEQ, L.EMB))
-    logits = jnp.einsum("bsh,hv->bsv", x,
-                        params["lm_head"].value.astype(dt(rt.compute_dtype)))
-    logits = L.constrain(logits, rules, (L.BATCH, L.SEQ, L.VOCAB))
-    if spec.final_softcap:
-        logits = L._softcap(logits.astype(jnp.float32), spec.final_softcap)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(params["ln_f"], x)
+        x = L.constrain(x, rules, (L.BATCH, L.SEQ, L.EMB))
+        logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].value.astype(
+            dt(rt.compute_dtype)))
+        logits = L.constrain(logits, rules, (L.BATCH, L.SEQ, L.VOCAB))
+        if spec.final_softcap:
+            logits = L._softcap(logits.astype(jnp.float32), spec.final_softcap)
     return logits
 
 
@@ -263,7 +265,12 @@ def loss_fn(params: dict, batch: dict, spec, rt: RuntimeCfg,
             rules: Optional[AxisRules] = None) -> jax.Array:
     logits = forward(params, batch["tokens"], spec, rt, rules,
                      frames=batch.get("frames"), vision=batch.get("vision"))
-    labels = batch["labels"]
+    with jax.named_scope("loss"):
+        return _cross_entropy(logits, batch["labels"], rt)
+
+
+def _cross_entropy(logits, labels, rt: RuntimeCfg) -> jax.Array:
+    """Mean token cross-entropy of ``logits`` against ``labels``."""
     if logits.shape[1] != labels.shape[1]:       # VLM: vision positions unlabeled
         logits = logits[:, -labels.shape[1]:]
     s = labels.shape[1]
@@ -345,7 +352,8 @@ def init_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
 def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
                 rules: Optional[AxisRules] = None) -> tuple[jax.Array, dict]:
     """One decode step: tokens [B, 1] -> (logits [B,1,V], new cache)."""
-    x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
     prefix_n, period = layer_pattern(spec)
     new_cache = {"prefix": [], "slots": []}
     li = 0
@@ -374,9 +382,10 @@ def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
         x, ncs = jax.lax.scan(step, x, scanned)
         new_cache["slots"].append(ncs)
 
-    x = L.rms_norm(params["ln_f"], x)
-    logits = jnp.einsum("bsh,hv->bsv", x,
-                        params["lm_head"].value.astype(dt(rt.compute_dtype)))
-    if spec.final_softcap:
-        logits = L._softcap(logits.astype(jnp.float32), spec.final_softcap)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(params["ln_f"], x)
+        logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].value.astype(
+            dt(rt.compute_dtype)))
+        if spec.final_softcap:
+            logits = L._softcap(logits.astype(jnp.float32), spec.final_softcap)
     return logits, new_cache

@@ -9,9 +9,12 @@ Three coupled pieces (see each module's docstring):
   :class:`~repro.obs.timeline.UtilizationReport`.  Reached through
   ``Trace.timeline(...)`` / ``Job.timeline(...)``.
 * :mod:`repro.obs.spans` — self-profiling tracer for the generator
-  itself (``REPRO_TRACE=1`` or :func:`profiled`), same export format.
-* :mod:`repro.obs.metrics` — counters/gauges/histograms +
-  :func:`snapshot`/:func:`diff`, surfaced by ``python -m repro.obs``.
+  and the runtime (``REPRO_TRACE=1`` or :func:`profiled`; bridged into
+  the JAX profiler's trace whenever that records), same export format;
+  :func:`runtime_hooks` adds garbage-collection and compile instruments.
+* :mod:`repro.obs.metrics` — counters/gauges/histograms (each keeping
+  its newest samples in order) + :func:`snapshot`/:func:`diff`,
+  surfaced by ``python -m repro.obs``.
 
 ``spans``/``metrics``/``log`` are stdlib-only and import eagerly;
 ``timeline`` depends on the core simulation layer and loads lazily so
@@ -22,12 +25,14 @@ from __future__ import annotations
 from .log import configure as configure_logging
 from .log import get_logger
 from .metrics import (REGISTRY, counter, diff, gauge, histogram, snapshot)
-from .spans import (Profile, enabled, profiled, span, take_events, traced)
+from .spans import (Profile, enabled, profiled, runtime_hooks, span,
+                    take_events, timed, traced)
 
 __all__ = [
     "configure_logging", "get_logger",
     "REGISTRY", "counter", "gauge", "histogram", "snapshot", "diff",
-    "span", "traced", "profiled", "enabled", "take_events", "Profile",
+    "span", "timed", "traced", "profiled", "enabled", "take_events",
+    "Profile", "runtime_hooks",
     # lazy (from .timeline):
     "Timeline", "TimelineEvent", "UtilizationReport",
     "build_timeline", "job_timeline", "profile_chrome_trace",

@@ -17,7 +17,9 @@ render and compare saved snapshots.
 from __future__ import annotations
 
 import bisect
+import math
 import threading
+from collections import deque
 from dataclasses import dataclass, field
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
@@ -25,6 +27,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
            "format_snapshot", "format_diff", "reset"]
 
 _HIST_BOUNDS = (1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 1e-1, 1.0, 10.0, 100.0)
+RING = 65_536                 # newest samples a histogram keeps in order
 
 
 @dataclass
@@ -52,7 +55,9 @@ class Gauge:
 
 @dataclass
 class Histogram:
-    """Fixed-bound histogram plus running sum/count/min/max.
+    """Fixed-bound histogram plus running sum/count/min/max, and its
+    newest ``RING`` samples in the order observed, from which
+    :meth:`newest` and :meth:`quantile` read exact values.
 
     Bounds default to decades from 1µs to 100s — sized for wall-clock
     durations of pipeline stages."""
@@ -63,6 +68,7 @@ class Histogram:
     count: int = 0
     vmin: float = float("inf")
     vmax: float = float("-inf")
+    samples: deque = field(default_factory=lambda: deque(maxlen=RING))
 
     def __post_init__(self):
         if not self.counts:
@@ -70,6 +76,7 @@ class Histogram:
 
     def observe(self, v: float) -> None:
         self.counts[bisect.bisect_left(self.bounds, v)] += 1
+        self.samples.append(v)
         self.total += v
         self.count += 1
         if v < self.vmin:
@@ -81,12 +88,31 @@ class Histogram:
     def mean(self) -> float:
         return self.total / self.count if self.count else 0.0
 
+    def newest(self, n: int | None = None) -> list:
+        """The newest ``n`` samples (all that are kept if None), oldest
+        first; fewer where fewer were observed or kept."""
+        kept = list(self.samples)
+        return kept if n is None else kept[max(0, len(kept) - n):]
+
+    def quantile(self, q: float, n: int | None = None) -> float | None:
+        """The ``q`` quantile (0..1) of the newest ``n`` samples, linear
+        between order statistics (numpy's default); None with none."""
+        xs = sorted(self.newest(n))
+        if not xs:
+            return None
+        pos = q * (len(xs) - 1)
+        lo = math.floor(pos)
+        hi = min(lo + 1, len(xs) - 1)
+        return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
+
 
 class Registry:
     """Named instruments, created on first use."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        # re-entrant: the ``py.gc`` hook (repro.obs.spans.runtime_hooks)
+        # can run inside any allocation, also one made under the lock
+        self._lock = threading.RLock()
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._hists: dict[str, Histogram] = {}
@@ -122,11 +148,14 @@ class Registry:
         """Plain-dict dump of every instrument (JSON-serializable)."""
         with self._lock:
             out: dict = {"counters": {}, "gauges": {}, "histograms": {}}
-            for name, c in sorted(self._counters.items()):
+            # copies first: a hook may add an instrument mid-iteration
+            counters, gauges = dict(self._counters), dict(self._gauges)
+            hists = dict(self._hists)
+            for name, c in sorted(counters.items()):
                 out["counters"][name] = c.value
-            for name, g in sorted(self._gauges.items()):
+            for name, g in sorted(gauges.items()):
                 out["gauges"][name] = g.value
-            for name, h in sorted(self._hists.items()):
+            for name, h in sorted(hists.items()):
                 out["histograms"][name] = {
                     "count": h.count, "total": h.total, "mean": h.mean,
                     "min": (None if h.count == 0 else h.vmin),

@@ -5,12 +5,30 @@ batch against a seq_len cache).  The engine adds simple continuous
 batching on top: finished sequences release their slot, queued requests
 claim it, and the cache row is reset in place — the slot-level pattern
 behind production LLM servers, on a static-shape substrate XLA likes.
+
+Instruments (``repro.obs``; always on, a few µs a step).  Spans, which
+reach the JAX profiler's trace whenever it records: ``engine.admit``
+(args ``requests``, ``prompt_tokens``, ``rids``) when a request is
+admitted, and ``engine.step`` (``step``, ``rows``) with its children
+``engine.feed`` (the token batch built on the host), ``engine.dispatch``
+(the ``step_fn`` call) and ``engine.sample`` (argmax and its host read:
+the host waits for the device there).  Histograms of ordered samples:
+``engine.admit_s`` per admission, ``engine.queue_wait_s`` per request
+(submit to the end of its own admission), ``engine.host_gap_s`` per step
+after the engine's first (end of the previous ``engine.sample`` to this
+``engine.dispatch``, less the admission time between them),
+``engine.useful_rows`` per step (rows that appended an output token) and
+``engine.ttft_s`` per request (submit to the host's read of its first
+token).  Counters: ``engine.steps``, ``engine.requests_admitted``,
+``engine.prompt_tokens_admitted``, ``engine.tokens_out``,
+``engine.rows_prefill``.  Each request keeps its own times
+(``t_submit``, ``t_admit``, ``t_first``; perf_counter) under its ``rid``.
 """
 from __future__ import annotations
 
-import dataclasses
+import time
 from dataclasses import dataclass, field
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +36,7 @@ import numpy as np
 
 from repro.models import lm
 from repro.models.common import AxisRules, RuntimeCfg
+from repro.obs import metrics, runtime_hooks, span, timed
 
 
 @dataclass
@@ -27,6 +46,9 @@ class Request:
     max_new: int = 16
     out: list = field(default_factory=list)
     done: bool = False
+    t_submit: Optional[float] = None         # perf_counter times
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 def make_serve_step(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None):
@@ -56,21 +78,41 @@ class Engine:
         self.step_fn = jax.jit(make_serve_step(spec, rt, rules))
         self.tokens = jnp.zeros((batch_slots, 1), jnp.int32)
         self.queue: list[Request] = []
+        self.n_steps = 0
+        self._sampled: Optional[float] = None   # end of the last sample
+        self._admit_s = 0.0                     # admission time since
+        runtime_hooks()
 
     def submit(self, req: Request):
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def _admit(self):
-        for i, s in enumerate(self.slots):
-            if s is None and self.queue:
-                req = self.queue.pop(0)
-                self.slots[i] = req
-                # feed the prompt token-by-token (prefill via decode path)
-                for t in req.prompt:
-                    tok = self.tokens.at[i, 0].set(int(t))
-                    self.tokens = tok
-                    # note: per-slot prefill shares the batched step below
-                req._fed = 0
+        if not self.queue:
+            return
+        take = self.queue[:sum(s is None for s in self.slots)]
+        if not take:
+            return
+        n_tok = sum(len(r.prompt) for r in take)
+        with timed("engine.admit", requests=len(take), prompt_tokens=n_tok,
+                   rids=[r.rid for r in take]) as adm:
+            for i, s in enumerate(self.slots):
+                if s is None and self.queue:
+                    req = self.queue.pop(0)
+                    self.slots[i] = req
+                    # feed the prompt token-by-token (prefill via decode path)
+                    for t in req.prompt:
+                        tok = self.tokens.at[i, 0].set(int(t))
+                        self.tokens = tok
+                        # note: per-slot prefill shares the batched step below
+                    req._fed = 0
+                    req.t_admit = time.perf_counter()
+                    metrics.histogram("engine.queue_wait_s").observe(
+                        req.t_admit - req.t_submit)
+        self._admit_s += adm.dur
+        metrics.histogram("engine.admit_s").observe(adm.dur)
+        metrics.counter("engine.requests_admitted").inc(len(take))
+        metrics.counter("engine.prompt_tokens_admitted").inc(n_tok)
 
     def run(self, max_steps: int = 64) -> list[Request]:
         """Greedy-decode all queued requests; returns finished requests."""
@@ -79,27 +121,52 @@ class Engine:
         for _ in range(max_steps):
             if all(s is None for s in self.slots) and not self.queue:
                 break
-            # build the batched token: prompts feed first, then argmax
-            tok_host = np.zeros((len(self.slots), 1), np.int32)
-            for i, req in enumerate(self.slots):
-                if req is None:
-                    continue
-                if req._fed < len(req.prompt):
-                    tok_host[i, 0] = req.prompt[req._fed]
-                    req._fed += 1
-                elif req.out:
-                    tok_host[i, 0] = req.out[-1]
-            logits, self.cache = self.step_fn(self.params, self.cache,
-                                              jnp.asarray(tok_host))
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-            for i, req in enumerate(self.slots):
-                if req is None:
-                    continue
-                if req._fed >= len(req.prompt):
-                    req.out.append(int(nxt[i]))
-                if len(req.out) >= req.max_new:
-                    req.done = True
-                    finished.append(req)
-                    self.slots[i] = None
+            with span("engine.step", step=self.n_steps) as st:
+                with span("engine.feed"):
+                    # build the batched token: prompts feed first, then argmax
+                    tok_host = np.zeros((len(self.slots), 1), np.int32)
+                    live = prefill = 0
+                    for i, req in enumerate(self.slots):
+                        if req is None:
+                            continue
+                        live += 1
+                        if req._fed < len(req.prompt):
+                            tok_host[i, 0] = req.prompt[req._fed]
+                            req._fed += 1
+                            prefill += 1
+                        elif req.out:
+                            tok_host[i, 0] = req.out[-1]
+                st.set(rows=live)
+                t_dispatch = time.perf_counter()
+                with span("engine.dispatch"):
+                    logits, self.cache = self.step_fn(self.params, self.cache,
+                                                      jnp.asarray(tok_host))
+                with span("engine.sample"):
+                    nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                t_sampled = time.perf_counter()
+                useful = 0
+                for i, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    if req._fed >= len(req.prompt):
+                        req.out.append(int(nxt[i]))
+                        useful += 1
+                        if len(req.out) == 1:
+                            req.t_first = t_sampled
+                            metrics.histogram("engine.ttft_s").observe(
+                                req.t_first - req.t_submit)
+                    if len(req.out) >= req.max_new:
+                        req.done = True
+                        finished.append(req)
+                        self.slots[i] = None
+            if self._sampled is not None:
+                metrics.histogram("engine.host_gap_s").observe(
+                    t_dispatch - self._sampled - self._admit_s)
+            self._sampled, self._admit_s = t_sampled, 0.0
+            self.n_steps += 1
+            metrics.counter("engine.steps").inc()
+            metrics.counter("engine.rows_prefill").inc(prefill)
+            metrics.counter("engine.tokens_out").inc(useful)
+            metrics.histogram("engine.useful_rows").observe(useful)
             self._admit()
         return finished

@@ -81,8 +81,9 @@ def adamw_update(params, grads, opt_state, cfg: OptCfg):
     value tree.  Returns (new params, new opt state, metrics)."""
     step = opt_state["step"]
     lr = schedule(cfg, step)
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / (gnorm + 1e-9))
+    with jax.named_scope("clip"):
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / (gnorm + 1e-9))
 
     flat_p, treedef = jax.tree.flatten(
         params, is_leaf=lambda x: isinstance(x, Param))

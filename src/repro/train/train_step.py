@@ -1,5 +1,9 @@
 """The jit-able training step: loss -> grad -> (compress) -> AdamW.
 
+The step's ops carry the named scopes ``grad`` (forward and backward,
+with the model's own scopes inside) and ``adamw`` (with ``clip``, the
+global-norm clip, inside) in their HLO ``op_name``.
+
 Microbatched gradient accumulation runs as a ``lax.scan`` over batch
 splits (pipeline-style utilization without PP's bubbles on a 2-D mesh);
 the optional top-k gradient compression with error feedback sits between
@@ -15,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.models import lm
 from repro.models.common import AxisRules, Param, RuntimeCfg
+from repro.obs import runtime_hooks
 from .compress import topk_compress_decompress
 from .optimizer import OptCfg, adamw_update
 
@@ -26,6 +31,7 @@ def make_train_step(spec, rt: RuntimeCfg, opt_cfg: OptCfg,
 
     ``opt_state`` may carry an ``ef`` error-feedback buffer when
     compression is enabled."""
+    runtime_hooks()
 
     def loss(params, batch):
         return lm.loss_fn(params, batch, spec, rt, rules)
@@ -59,7 +65,8 @@ def make_train_step(spec, rt: RuntimeCfg, opt_cfg: OptCfg,
         return tl * scale, jax.tree.map(lambda g: g * scale, tg)
 
     def train_step(params, opt_state, batch):
-        l, grads = grads_of(params, batch)
+        with jax.named_scope("grad"):
+            l, grads = grads_of(params, batch)
         grads = jax.tree.map(lambda g: getattr(g, "value", g), grads,
                              is_leaf=lambda x: isinstance(x, Param))
         metrics = {"loss": l}
@@ -70,7 +77,8 @@ def make_train_step(spec, rt: RuntimeCfg, opt_cfg: OptCfg,
             opt_state = {**opt_state, "ef": ef}
         ef = opt_state.pop("ef", None) if isinstance(opt_state, dict) else None
         core = {k: opt_state[k] for k in ("m", "v", "step")}
-        params, core, om = adamw_update(params, grads, core, opt_cfg)
+        with jax.named_scope("adamw"):
+            params, core, om = adamw_update(params, grads, core, opt_cfg)
         new_opt = dict(core)
         if ef is not None:
             new_opt["ef"] = ef

@@ -86,3 +86,18 @@ def test_serve_launcher_answers_every_request(no_repo_cache):
                                "--kv-len", "64"])
     assert sorted(r.rid for r in done) == [0, 1, 2]
     assert all(len(r.out) == 2 for r in done)
+
+
+def test_train_launcher_times_each_step_in_spans(no_repo_cache, monkeypatch):
+    from repro.obs import profiled
+    monkeypatch.chdir(no_repo_cache)
+    with profiled() as prof:
+        res = train.main(["--arch", "rwkv6-7b", "--smoke", "--steps", "2",
+                          "--batch", "2", "--seq", "32"])
+    assert len(res["losses"]) == 2
+    steps = [e for e in prof.events if e.name == "train.step"]
+    assert [e.args["step"] for e in steps] == [0, 1]
+    ids = {e.id for e in steps}
+    for name in ("train.data", "train.dispatch", "train.sync"):
+        kids = [e for e in prof.events if e.name == name]
+        assert len(kids) == 2 and {k.parent for k in kids} == ids

@@ -1,0 +1,241 @@
+"""Runtime instruments (``repro.obs``): spans bridged into the JAX
+profiler's trace, span ids and parents, ordered histogram samples and
+their quantiles, the garbage-collection and compile hooks, the serve
+engine's spans, histograms and counters, and the model's named scopes in
+the train step's HLO."""
+import gc
+import glob
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.configs import get
+from repro.core import ModelSpec
+from repro.models import RuntimeCfg, init_params
+from repro.obs import metrics, profiled, runtime_hooks, span, timed
+from repro.obs import spans as obs_spans
+
+
+@pytest.fixture(autouse=True)
+def _clean_obs():
+    obs_spans.disable()
+    obs_spans.take_events()
+    metrics.reset()
+    yield
+    obs_spans.disable()
+    obs_spans.take_events()
+    metrics.reset()
+
+
+# --------------------------------------------------------------------------
+# the profiler bridge
+# --------------------------------------------------------------------------
+
+def _host_events(trace_dir, names) -> dict:
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out[ev.name] = {"plane": plane.name, "line": line.name,
+                                    "start": ev.start_ns,
+                                    "end": ev.start_ns + ev.duration_ns,
+                                    "stats": dict(ev.stats)}
+    return out
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_span_reaches_the_profiler_trace(tmp_path, recorder):
+    f = jax.jit(lambda x: x * 2)
+    f(jnp.ones(8)).block_until_ready()
+    assert span("test.off") is obs_spans._NOOP
+    if recorder:
+        obs_spans.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.outer"):
+            with span("test.inner", rows=3, rids=[4, 5]) as sp:
+                f(jnp.ones(8)).block_until_ready()
+                sp.set(done=1)
+    finally:
+        jax.profiler.stop_trace()
+        obs_spans.disable()
+    # everything off again: the shared no-op, no clock read
+    assert span("test.off", k=1) is obs_spans._NOOP
+    ev = _host_events(tmp_path, {"test.outer", "test.inner"})
+    outer, inner = ev["test.outer"], ev["test.inner"]
+    assert (inner["plane"], inner["line"]) == (outer["plane"], outer["line"])
+    assert outer["start"] <= inner["start"] < inner["end"] <= outer["end"]
+    assert inner["stats"] == {"rows": 3, "rids": "[4, 5]", "done": 1}
+    recorded = [e for e in obs_spans.take_events() if e.name == "test.inner"]
+    assert len(recorded) == recorder
+    if recorder:
+        assert recorded[0].args == {"rows": 3, "rids": [4, 5], "done": 1}
+
+
+def test_spans_record_their_parent():
+    with profiled() as prof:
+        with span("a"):
+            with span("b"):
+                with span("c"):
+                    pass
+            with span("d"):
+                pass
+    by = {e.name: e for e in prof.events}
+    assert by["a"].parent == 0
+    assert by["b"].parent == by["d"].parent == by["a"].id
+    assert by["c"].parent == by["b"].id
+    assert len({e.id for e in prof.events}) == 4
+    tot = prof.totals()
+    assert tot["a"]["self_s"] == pytest.approx(
+        by["a"].dur - by["b"].dur - by["d"].dur, abs=1e-12)
+
+
+def test_timed_reads_the_clock_with_tracing_off():
+    with timed("test.t") as t:
+        time.sleep(0.01)
+    assert t.dur >= 0.01 and t.t1 - t.t0 == t.dur
+    assert obs_spans.take_events() == []
+
+
+# --------------------------------------------------------------------------
+# histograms and process hooks
+# --------------------------------------------------------------------------
+
+def test_histogram_quantile_over_newest_samples():
+    h = metrics.histogram("q")
+    warm = [1e3] * 5
+    window = [float(x) for x in (7, 3, 9, 1, 5, 2, 8, 10, 4, 6)]
+    for x in warm + window:
+        h.observe(x)
+    assert h.newest(10) == window
+    assert h.newest(100) == warm + window
+    for q in (0.0, 0.25, 0.5, 0.95, 1.0):
+        assert h.quantile(q, 10) == pytest.approx(np.percentile(window,
+                                                                100 * q))
+    assert h.quantile(1.0) == 1e3
+    assert metrics.histogram("empty").quantile(0.5) is None
+    assert set(metrics.snapshot(caches=False)["histograms"]["q"]) == {
+        "count", "total", "mean", "min", "max", "bounds", "buckets"}
+
+
+def test_histogram_keeps_a_bounded_ring():
+    h = metrics.histogram("ring")
+    for i in range(metrics.RING + 10):
+        h.observe(float(i))
+    assert h.count == metrics.RING + 10
+    assert len(h.newest()) == metrics.RING
+    assert h.newest(1) == [float(metrics.RING + 9)]
+
+
+def test_gc_hook_spans_and_histogram():
+    runtime_hooks()
+    runtime_hooks()                       # idempotent
+    assert gc.callbacks.count(obs_spans._on_gc) == 1
+    with profiled() as prof:
+        with span("test.work"):
+            gc.collect()
+    work, = [e for e in prof.events if e.name == "test.work"]
+    pauses = [e for e in prof.events if e.name == "py.gc"]
+    full = [e for e in pauses if e.args["generation"] == 2]
+    assert full and "collected" in full[-1].args
+    assert full[-1].parent == work.id
+    assert metrics.histogram("py.gc_s").count >= len(pauses)
+
+
+def test_compile_hook_counts_backend_compiles():
+    runtime_hooks()
+    c = float(time.time_ns() % 1_000_003)   # a program no cache holds
+    jax.jit(lambda x: x * c + 1.0)(jnp.ones(3)).block_until_ready()
+    assert metrics.counter("jit.compiles").value >= 1
+    assert metrics.histogram("jit.compile_s").total > 0
+
+
+# --------------------------------------------------------------------------
+# the serve engine
+# --------------------------------------------------------------------------
+
+def test_engine_instruments_two_slots():
+    from repro.serve import Engine, Request
+    spec = ModelSpec(name="m", n_layers=2, d_model=64, n_heads=4,
+                     n_kv_heads=2, d_ff=128, vocab=256)
+    rt = RuntimeCfg(attention_impl="naive")
+    eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
+                 batch_slots=2, kv_len=64)
+    reqs = [Request(rid=i, prompt=np.array([1, 2, 3 + i]), max_new=4)
+            for i in range(3)]
+    for r in reqs:
+        eng.submit(r)
+    with profiled() as prof:
+        done = eng.run(max_steps=40)
+    assert len(done) == 3
+    steps = eng.n_steps
+    h, c = metrics.histogram, metrics.counter
+    assert h("engine.host_gap_s").count == steps - 1
+    assert min(h("engine.host_gap_s").newest()) >= 0
+    assert h("engine.useful_rows").count == c("engine.steps").value == steps
+    tokens = sum(len(r.out) for r in reqs)
+    assert sum(h("engine.useful_rows").newest()) == tokens
+    assert c("engine.tokens_out").value == tokens
+    assert h("engine.queue_wait_s").count == h("engine.ttft_s").count == 3
+    assert h("engine.admit_s").count == 2      # two at once, then one
+    assert c("engine.requests_admitted").value == 3
+    assert c("engine.prompt_tokens_admitted").value == 9
+    assert c("engine.rows_prefill").value == 9
+    assert all(r.t_submit <= r.t_admit <= r.t_first for r in reqs)
+    # each step's phases are its children; admissions carry their rids
+    step_ids = {e.id for e in prof.events if e.name == "engine.step"}
+    assert len(step_ids) == steps
+    for name in ("engine.feed", "engine.dispatch", "engine.sample"):
+        kids = [e for e in prof.events if e.name == name]
+        assert len(kids) == steps and all(k.parent in step_ids for k in kids)
+    admits = [e for e in prof.events if e.name == "engine.admit"]
+    assert sorted(i for a in admits for i in a.args["rids"]) == [0, 1, 2]
+    assert [a.args["prompt_tokens"] for a in admits] == [6, 3]
+
+
+# --------------------------------------------------------------------------
+# named scopes in the model
+# --------------------------------------------------------------------------
+
+COMMON = ("embed", "lm_head", "loss", "grad", "adamw", "clip")
+
+
+def _hlo_scopes(arch: str) -> set:
+    from jax._src.lib import xla_client as xc
+
+    from repro.launch.train import runtime_cfg
+    from repro.train import OptCfg, init_opt_state, make_train_step
+    spec = get(arch).smoke
+    rt = runtime_cfg(64)
+    params = jax.eval_shape(lambda k: init_params(spec, rt, k),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(init_opt_state, params)
+    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32)
+             for k in ("tokens", "labels")}
+    low = jax.jit(make_train_step(spec, rt, OptCfg())).lower(params, opt,
+                                                             batch)
+    opts = xc._xla.HloPrintOptions.short_parsable()
+    opts.print_metadata = True
+    hlo = low.compiler_ir("hlo").as_hlo_module().to_string(opts)
+    found = set()
+    for name in re.findall(r'op_name="([^"]*)"', hlo):
+        for part in name.split("/"):
+            while (m := re.fullmatch(r"[\w.]+\((.*)\)", part)):
+                part = m.group(1)           # jvp(ffn) -> ffn
+            found.add(part)
+    return found
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("rwkv6-7b", ("rwkv6_time_mix", "rwkv6_channel_mix")),
+    ("granite-34b", ("gqa_attention", "ffn")),
+])
+def test_train_step_hlo_carries_named_scopes(arch, layers):
+    assert set(COMMON + layers) <= _hlo_scopes(arch)
