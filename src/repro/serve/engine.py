@@ -6,6 +6,11 @@ batching on top: finished sequences release their slot, queued requests
 claim it, and the cache row is reset in place — the slot-level pattern
 behind production LLM servers, on a static-shape substrate XLA likes.
 
+Admission is host-only bookkeeping: it places queued requests in free
+slots and touches no device buffer.  Prompts reach the device through
+``run``'s host-built token batch, one position per step (prefill by
+decode), in the same ``step_fn`` call that decodes the other rows.
+
 Instruments (``repro.obs``; always on, a few µs a step).  Spans, which
 reach the JAX profiler's trace whenever it records: ``engine.admit``
 (args ``requests``, ``prompt_tokens``, ``rids``) when a request is
@@ -76,7 +81,6 @@ class Engine:
         self.slots: list[Optional[Request]] = [None] * batch_slots
         self.cache = lm.init_cache(spec, rt, batch_slots, kv_len)
         self.step_fn = jax.jit(make_serve_step(spec, rt, rules))
-        self.tokens = jnp.zeros((batch_slots, 1), jnp.int32)
         self.queue: list[Request] = []
         self.n_steps = 0
         self._sampled: Optional[float] = None   # end of the last sample
@@ -88,6 +92,8 @@ class Engine:
         self.queue.append(req)
 
     def _admit(self):
+        """Move queued requests into free slots (host only: no device
+        work).  ``run`` then feeds each prompt one position per step."""
         if not self.queue:
             return
         take = self.queue[:sum(s is None for s in self.slots)]
@@ -100,11 +106,6 @@ class Engine:
                 if s is None and self.queue:
                     req = self.queue.pop(0)
                     self.slots[i] = req
-                    # feed the prompt token-by-token (prefill via decode path)
-                    for t in req.prompt:
-                        tok = self.tokens.at[i, 0].set(int(t))
-                        self.tokens = tok
-                        # note: per-slot prefill shares the batched step below
                     req._fed = 0
                     req.t_admit = time.perf_counter()
                     metrics.histogram("engine.queue_wait_s").observe(

@@ -200,6 +200,31 @@ def test_engine_instruments_two_slots():
     assert [a.args["prompt_tokens"] for a in admits] == [6, 3]
 
 
+def test_engine_admit_makes_no_device_transfer():
+    from repro.serve import Engine, Request
+    spec = ModelSpec(name="m", n_layers=2, d_model=64, n_heads=4,
+                     n_kv_heads=2, d_ff=128, vocab=256)
+    rt = RuntimeCfg(attention_impl="naive")
+    eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
+                 batch_slots=8, kv_len=128)
+    rng = np.random.default_rng(0)
+    lens = [97, 2, 50, 13, 97, 7, 31, 64, 5, 20]
+    reqs = [Request(rid=i, prompt=rng.integers(1, 256, n).astype(np.int32))
+            for i, n in enumerate(lens)]
+    for r in reqs:
+        eng.submit(r)
+    with jax.transfer_guard_host_to_device("disallow"):
+        eng._admit()
+    assert eng.slots == reqs[:8] and eng.queue == reqs[8:]
+    assert all(r._fed == 0 and r.t_admit >= r.t_submit for r in reqs[:8])
+    assert all(r.t_admit is None for r in reqs[8:])
+    c, h = metrics.counter, metrics.histogram
+    assert c("engine.requests_admitted").value == 8
+    assert c("engine.prompt_tokens_admitted").value == sum(lens[:8])
+    assert h("engine.admit_s").count == 1
+    assert h("engine.queue_wait_s").count == 8
+
+
 # --------------------------------------------------------------------------
 # named scopes in the model
 # --------------------------------------------------------------------------

@@ -165,3 +165,28 @@ def test_serve_engine_generates():
     out2 = eng2.run(max_steps=40)[0].out
     ref_ = [r for r in done if r.rid == 0][0].out
     assert out2 == ref_
+
+
+def test_serve_wave_matches_forward():
+    """A wave admitted together from position 0, prompts of different
+    lengths: each served token is the full forward pass's best at its
+    position, up to rounding (the benchmark's served-logit gap)."""
+    from repro.models import lm
+    from repro.serve import Engine, Request
+    params = init_params(SPEC, RT, jax.random.PRNGKey(1))
+    eng = Engine(SPEC, RT, params, batch_slots=6, kv_len=64)
+    rng = np.random.default_rng(3)
+    sizes = [(1, 9), (2, 5), (5, 12), (9, 3), (17, 8), (30, 1)]
+    reqs = [Request(rid=i, max_new=o, prompt=rng.integers(
+        1, SPEC.vocab, p).astype(np.int32)) for i, (p, o) in enumerate(sizes)]
+    for r in reqs:
+        eng.submit(r)
+    done = eng.run(max_steps=64)
+    assert sorted(r.rid for r in done) == list(range(len(sizes)))
+    for r, (p, o) in zip(reqs, sizes):
+        assert len(r.out) == o
+        seq = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
+        logits = np.asarray(lm.forward(params, jnp.asarray(seq[None]), SPEC,
+                                       RT)[0, p - 1:], np.float64)
+        gap = logits.max(-1) - logits[np.arange(o), r.out]
+        assert gap.max() < 0.05, (r.rid, gap)
