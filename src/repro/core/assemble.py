@@ -9,6 +9,7 @@ symbolic dims from the spec + workload shape.
 from __future__ import annotations
 
 import math
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -21,21 +22,52 @@ from .symbolic import Env
 
 @dataclass(frozen=True)
 class MoESpec:
+    """Routed experts.  Routing: softmax over all ``n_experts``; with
+    ``topk_group`` < ``n_group`` a token reaches only the ``topk_group``
+    groups whose best expert scores highest (deepseek-v2's
+    group_limited_greedy); top ``top_k`` experts there; gates
+    renormalized to sum 1 if ``norm_topk``, else scaled by
+    ``routed_scale``.  A layer may hold a share of the experts:
+    ``n_held`` of them (0: all), the ``held_group``-th block of
+    ``n_held``; it computes only their part of the result."""
     n_experts: int
     top_k: int
     n_shared: int = 0
     d_expert: int = 0            # per-expert ffn width
     every: int = 1               # MoE every k-th layer (jamba: 2)
     first_dense: bool = False    # deepseek: layer 0 is a dense FFN
+    n_group: int = 1             # routing groups of experts
+    topk_group: int = 0          # groups a token may reach; 0 -> all
+    norm_topk: bool = True       # renormalize the top-k gates
+    routed_scale: float = 1.0    # gate scale when not renormalized
+    n_held: int = 0              # experts this layer holds; 0 -> all
+    held_group: int = 0          # which block of n_held it holds
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLASpec:
+    """Latent attention.  RoPE on the ``rope_dim`` part at
+    ``rope_theta``; ``rope_factor`` > 1 turns on yarn scaling as the
+    published deepseek-v2 modeling code has it (frequencies blended over
+    a ramp set by ``beta_fast``/``beta_slow`` at ``rope_original_max``
+    positions, softmax scaled by mscale(``mscale_all_dim``)²; its cos/sin
+    factor mscale(mscale) / mscale(mscale_all_dim) is taken as 1, as
+    deepseek-v2 has both 0.707)."""
     kv_lora: int = 512
     q_lora: int = 1536
     rope_dim: int = 64
     nope_dim: int = 128
     v_dim: int = 128
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -73,6 +105,13 @@ class ModelSpec:
     enc_seq: int = 1500                    # encoder frames (whisper stub)
     vision_seq: int = 0                    # prepended vision tokens (VLM stub)
     rwkv_decay_rank: int = 64
+
+    def __post_init__(self):
+        # a spec read from JSON holds its nested groups as mappings
+        for name, cls in (("moe", MoESpec), ("mla", MLASpec), ("ssm", SSMSpec)):
+            v = getattr(self, name)
+            if isinstance(v, Mapping):
+                object.__setattr__(self, name, cls(**v))
 
     @property
     def head_dim(self) -> int:

@@ -58,11 +58,16 @@ def rms_norm(w: Param, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, *, theta: float = 10000.0) -> jax.Array:
-    """Rotary embedding over the last dim; positions [B, S]."""
+def rope(x: jax.Array, positions: jax.Array, *, theta: float = 10000.0,
+         inv_freq=None) -> jax.Array:
+    """Rotary embedding over the last dim; positions [B, S].  Frequencies
+    theta^(-2i/d) unless ``inv_freq`` [d/2] is given."""
     d = x.shape[-1]
     half = d // 2
-    freqs = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs         # [B,S,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     extra = x.ndim - 3                                              # head dims
@@ -75,6 +80,42 @@ def rope(x: jax.Array, positions: jax.Array, *, theta: float = 10000.0) -> jax.A
     return rot.astype(x.dtype)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def mla_rope(m) -> np.ndarray:
+    """``inv_freq`` [rope_dim/2] of an MLA spec's RoPE part.  With
+    ``rope_factor`` > 1, yarn as the published deepseek-v2 modeling code
+    has it: theta^(-2i/d) and the same over ``rope_factor`` blended along
+    a linear ramp between the dims whose wavelengths turn ``beta_fast``
+    and ``beta_slow`` times in ``rope_original_max`` positions (10 and 23
+    for deepseek-v2).  Its cos/sin factor, mscale(mscale) /
+    mscale(mscale_all_dim), is 1 there (both 0.707) and is left out."""
+    d = m.rope_dim
+    base = 1.0 / (m.rope_theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    if m.rope_factor <= 1:
+        return base
+
+    def dim_of(rotations):
+        return d * math.log(m.rope_original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(m.rope_theta))
+    lo = max(math.floor(dim_of(m.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(m.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - lo)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (base / m.rope_factor * ramp + base * (1.0 - ramp)).astype(np.float32)
+
+
+def mla_softmax_scale(m) -> float:
+    """1/sqrt(nope + rope dims), times mscale(mscale_all_dim)^2 under yarn
+    (deepseek-v2: 1.2608^2 / sqrt(192) = 0.114721)."""
+    scale = 1.0 / math.sqrt(m.nope_dim + m.rope_dim)
+    if m.mscale_all_dim:
+        scale *= _yarn_mscale(m.rope_factor, m.mscale_all_dim) ** 2
+    return scale
+
+
 def _softcap(x: jax.Array, cap: float) -> jax.Array:
     return (cap * jnp.tanh(x / cap)).astype(x.dtype)
 
@@ -84,9 +125,11 @@ def _softcap(x: jax.Array, cap: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def attn_naive(q, k, v, *, causal: bool, window: Optional[int],
-               softcap: Optional[float], q_offset: int = 0) -> jax.Array:
-    """q [B,Sq,N,G,D], k/v [B,Sk,N,D] -> [B,Sq,N,G,D]."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+               softcap: Optional[float], q_offset: int = 0,
+               scale: Optional[float] = None) -> jax.Array:
+    """q [B,Sq,N,G,D], k/v [B,Sk,N,D] -> [B,Sq,N,G,D]; scores scaled by
+    ``scale`` (1/sqrt(D) if None)."""
+    scale = scale or 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bsngd,bknd->bngsk", q, k).astype(jnp.float32) * scale
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
@@ -105,7 +148,8 @@ def attn_naive(q, k, v, *, causal: bool, window: Optional[int],
 
 def attn_chunked(q, k, v, *, causal: bool, window: Optional[int],
                  softcap: Optional[float], chunk: int = 1024,
-                 q_offset=0, q_block: bool = True) -> jax.Array:
+                 q_offset=0, q_block: bool = True,
+                 scale: Optional[float] = None) -> jax.Array:
     """Online-softmax (flash) attention: q blocked via lax.map, kv scanned.
 
     Live memory O(q_block·chunk) per step instead of O(Sq·Sk) — this is
@@ -121,22 +165,25 @@ def attn_chunked(q, k, v, *, causal: bool, window: Optional[int],
         def one(args):
             qi, off = args
             return _attn_flash(qi, k, v, causal=causal, window=window,
-                               softcap=softcap, chunk=chunk, q_offset=off)
+                               softcap=softcap, chunk=chunk, q_offset=off,
+                               scale=scale)
 
         out = jax.lax.map(one, (qblocks, offs))
         return out.transpose(1, 0, 2, 3, 4, 5).reshape(
             b, sq, n, g, out.shape[-1])
     return _attn_flash(q, k, v, causal=causal, window=window,
-                       softcap=softcap, chunk=chunk, q_offset=q_offset)
+                       softcap=softcap, chunk=chunk, q_offset=q_offset,
+                       scale=scale)
 
 
 def _attn_flash(q, k, v, *, causal: bool, window: Optional[int],
-                softcap: Optional[float], chunk: int, q_offset=0) -> jax.Array:
+                softcap: Optional[float], chunk: int, q_offset=0,
+                scale: Optional[float] = None) -> jax.Array:
     b, sq, n, g, d = q.shape
     sk = k.shape[1]
     if sk <= chunk and isinstance(q_offset, int):
         return attn_naive(q, k, v, causal=causal, window=window,
-                          softcap=softcap, q_offset=q_offset)
+                          softcap=softcap, q_offset=q_offset, scale=scale)
     nchunks = -(-sk // chunk)
     pad = nchunks * chunk - sk
     if pad:
@@ -144,7 +191,7 @@ def _attn_flash(q, k, v, *, causal: bool, window: Optional[int],
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kc = k.reshape(b, nchunks, chunk, n, k.shape[-1]).transpose(1, 0, 2, 3, 4)
     vc = v.reshape(b, nchunks, chunk, n, v.shape[-1]).transpose(1, 0, 2, 3, 4)
-    scale = 1.0 / math.sqrt(d)
+    scale = scale or 1.0 / math.sqrt(d)
     qpos = jnp.arange(sq) + q_offset
 
     def body(carry, ckv):
@@ -181,9 +228,12 @@ def _attn_flash(q, k, v, *, causal: bool, window: Optional[int],
 
 
 def attn_core(q, k, v, rt: RuntimeCfg, *, causal: bool, window=None,
-              softcap=None, q_offset: int = 0) -> jax.Array:
+              softcap=None, q_offset: int = 0,
+              scale: Optional[float] = None) -> jax.Array:
     if rt.attention_impl == "pallas":
         from repro.kernels import ops as kops
+        if scale is not None:         # the kernel scales by 1/sqrt(D)
+            q = q * (scale * math.sqrt(q.shape[-1]))
         return kops.flash_attention(q, k, v, causal=causal, window=window,
                                     softcap=softcap, q_offset=q_offset)
     if rt.attention_impl == "chunked":
@@ -194,10 +244,11 @@ def attn_core(q, k, v, rt: RuntimeCfg, *, causal: bool, window=None,
             functools.partial(attn_chunked, causal=causal, window=window,
                               softcap=softcap, chunk=rt.attn_chunk,
                               q_offset=q_offset,
-                              q_block=rt.attn_q_block), prevent_cse=False)
+                              q_block=rt.attn_q_block, scale=scale),
+            prevent_cse=False)
         return fn(q, k, v)
     return attn_naive(q, k, v, causal=causal, window=window,
-                      softcap=softcap, q_offset=q_offset)
+                      softcap=softcap, q_offset=q_offset, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -316,6 +367,10 @@ def init_mla(ini: Initializer, spec, prefix: str = "") -> dict:
 def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
                   cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+    """Multi-head latent attention.  The cache holds the normed latent
+    ``ckv`` [B,T,kv_lora] and the rotated ``kr`` [B,T,rope_dim].  With a
+    cache, attention runs in the latent space (``mla_decode``); without
+    one (prefill, training) keys and values are expanded per head."""
     m = spec.mla
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
@@ -325,41 +380,49 @@ def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
 
     ckv_new = rms_norm(p["ln_kv"], jnp.einsum("bsh,hr->bsr", h, cast(p["w_dkv"].value, rt)))
     kr_new = jnp.einsum("bsh,hd->bsd", h, cast(p["w_kr"].value, rt))
+    pos = cache["pos"] if cache is not None else 0
+    if positions is None:
+        positions = pos + jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+    inv_freq = mla_rope(m)
+    qr = rope(qr, positions, inv_freq=inv_freq)
+    kr_new = rope(kr_new[:, :, None], positions, inv_freq=inv_freq)[:, :, 0]
+    scale = mla_softmax_scale(m)
     if cache is not None:
-        pos = cache["pos"]
-        if positions is None:
-            positions = pos + jnp.zeros(x.shape[:2], jnp.int32)
-        qr = rope(qr, positions)
-        kr_new = rope(kr_new[:, :, None], positions)[:, :, 0]
         ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_new, pos, axis=1)
         kr = jax.lax.dynamic_update_slice_in_dim(cache["kr"], kr_new, pos, axis=1)
         new_cache = {"ckv": ckv, "kr": kr, "pos": pos + x.shape[1]}
-        q_offset = pos
+        with jax.named_scope("mla_decode"):
+            ctx = _mla_latent(p, qn, qr, ckv, kr, pos, scale, rt)
     else:
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
-        qr = rope(qr, positions)
-        kr_new = rope(kr_new[:, :, None], positions)[:, :, 0]
-        ckv, kr = ckv_new, kr_new
+        kn = jnp.einsum("btr,rnd->btnd", ckv_new, cast(p["w_uk"].value, rt))
+        vv = jnp.einsum("btr,rnd->btnd", ckv_new, cast(p["w_uv"].value, rt))
+        # nope+rope as one head dim; the rope key is shared by all heads
+        qq = jnp.concatenate([qn, qr], axis=-1)[:, :, :, None, :]   # [B,S,N,1,D]
+        kk = jnp.concatenate([kn, jnp.broadcast_to(
+            kr_new[:, :, None], kr_new.shape[:2] + (kn.shape[2], m.rope_dim))],
+            axis=-1)
+        ctx = attn_core(qq, kk, vv, rt, causal=True, scale=scale)[:, :, :, 0]
         new_cache = None
-        q_offset = 0
-
-    kn = jnp.einsum("btr,rnd->btnd", ckv, cast(p["w_uk"].value, rt))
-    vv = jnp.einsum("btr,rnd->btnd", ckv, cast(p["w_uv"].value, rt))
-    # concat nope+rope into one head dim and run the flash core (q scaled
-    # to fold the joint 1/sqrt(dn+dr) in, since the core scales by its own
-    # last-dim width)
-    d_all = m.nope_dim + m.rope_dim
-    qq = jnp.concatenate([qn, qr], axis=-1)[:, :, :, None, :]   # [B,S,N,1,D]
-    qq = qq * (math.sqrt(d_all) / math.sqrt(d_all))
-    kk_r = jnp.broadcast_to(kr[:, :, None], kr.shape[:2] + (kn.shape[2],
-                                                            m.rope_dim))
-    kk = jnp.concatenate([kn, kk_r], axis=-1)
-    qq = qq.swapaxes(3, 3)
-    out5 = attn_core(qq, kk, vv, rt, causal=True, q_offset=q_offset)
-    ctx = out5[:, :, :, 0]
     out = jnp.einsum("bsnd,ndh->bsh", ctx, cast(p["w_o"].value, rt))
     return x + constrain(out, rules, (BATCH, SEQ, EMB)), new_cache
+
+
+def _mla_latent(p: dict, qn, qr, ckv, kr, pos, scale: float,
+                rt: RuntimeCfg) -> jax.Array:
+    """Attention against the latent cache: the query absorbs ``w_uk``,
+    scores are (q_nope W_uk) . ckv + q_rope . kr, the weighted sum of
+    ``ckv`` goes through ``w_uv`` after.  ckv [B,T,R] and kr [B,T,Dr]
+    are read once; no per-head key or value is formed.  Positions past
+    ``pos`` + the query's own are masked."""
+    q_lat = jnp.einsum("bsnd,rnd->bsnr", qn, cast(p["w_uk"].value, rt))
+    f32 = jnp.float32
+    s = (jnp.einsum("bsnr,btr->bnst", q_lat, ckv, preferred_element_type=f32)
+         + jnp.einsum("bsnd,btd->bnst", qr, kr, preferred_element_type=f32)) * scale
+    qpos = pos + jnp.arange(qn.shape[1])
+    s = jnp.where(jnp.arange(ckv.shape[1])[None, :] <= qpos[:, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1).astype(ckv.dtype)
+    ctx = jnp.einsum("bnst,btr->bsnr", pr, ckv)
+    return jnp.einsum("bsnr,rnd->bsnd", ctx, cast(p["w_uv"].value, rt))
 
 
 # ---------------------------------------------------------------------------
@@ -397,18 +460,22 @@ def ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     return x + constrain(down, rules, (BATCH, SEQ, EMB))
 
 
+EXPERT_WEIGHTS = ("w_egate", "w_eup", "w_edown")
+
+
 def init_moe(ini: Initializer, spec, prefix: str = "") -> dict:
+    """The router over all ``n_experts``; the ``held`` experts' weights."""
     H = spec.d_model
     mo = spec.moe
     p = {
         "ln": ini(prefix + "ln_moe", (H,), (EMB,)),
         "w_router": ini(prefix + "w_router", (H, mo.n_experts), (EMB, "router"),
                         dtype=jnp.float32),
-        "w_egate": ini(prefix + "w_egate", (mo.n_experts, H, mo.d_expert),
+        "w_egate": ini(prefix + "w_egate", (mo.held, H, mo.d_expert),
                        (EXP, EMB, FFN)),
-        "w_eup": ini(prefix + "w_eup", (mo.n_experts, H, mo.d_expert),
+        "w_eup": ini(prefix + "w_eup", (mo.held, H, mo.d_expert),
                      (EXP, EMB, FFN)),
-        "w_edown": ini(prefix + "w_edown", (mo.n_experts, mo.d_expert, H),
+        "w_edown": ini(prefix + "w_edown", (mo.held, mo.d_expert, H),
                        (EXP, FFN, EMB), scale=1.0 / np.sqrt(mo.d_expert)),
     }
     if mo.n_shared:
@@ -417,15 +484,81 @@ def init_moe(ini: Initializer, spec, prefix: str = "") -> dict:
     return p
 
 
-def _route_and_compute(h, wr, wg, wu, wd, *, E: int, Kk: int,
-                       capacity_factor: float, a2a_axis: Optional[str],
-                       gather_axes: tuple = ()):
-    """Local routing + dispatch + expert matmuls (+ optional EP AllToAll).
+def route(h: jax.Array, wr: jax.Array, mo) -> tuple[jax.Array, jax.Array]:
+    """Gates [T,K] and experts [T,K] of tokens ``h`` [T,H]: softmax over
+    all experts (router in float32); with ``topk_group`` < ``n_group``
+    only the ``topk_group`` groups with the best single scores stay
+    (group_limited_greedy); top ``top_k`` of what stays; gates
+    renormalized if ``norm_topk``, else times ``routed_scale``."""
+    probs = jax.nn.softmax(jnp.einsum("th,he->te", h.astype(jnp.float32), wr),
+                           axis=-1)
+    G = mo.n_group
+    if 0 < mo.topk_group < G:
+        t, e = probs.shape
+        best = probs.reshape(t, G, e // G).max(-1)                  # [T,G]
+        _, gi = jax.lax.top_k(best, mo.topk_group)
+        keep = jax.nn.one_hot(gi, G, dtype=jnp.bool_).any(1)        # [T,G]
+        probs = jnp.where(jnp.repeat(keep, e // G, axis=1), probs, 0.0)
+    gates, idx = jax.lax.top_k(probs, mo.top_k)
+    if mo.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        gates = gates * mo.routed_scale
+    return gates, idx
 
-    ``h`` [b_loc, s, H] are this shard's tokens; expert weights are the
-    local slice [E_loc, H, F] when ``a2a_axis`` is set (else all E).
-    The explicit ``jax.lax.all_to_all`` pair over the expert axis is the
-    EP communication pattern the STG matcher predicts (Table IV)."""
+
+def _held(idx: jax.Array, mo) -> tuple[jax.Array, jax.Array]:
+    """Each assignment's expert among the held ones, ``held`` where this
+    layer does not hold it; and the tokens routed to each held expert."""
+    local = idx - mo.held_group * mo.held
+    mine = (local >= 0) & (local < mo.held)
+    local = jnp.where(mine, local, mo.held).reshape(-1)
+    counts = jnp.bincount(local, length=mo.held + 1)[:mo.held]
+    return local, counts.astype(jnp.int32)
+
+
+def _held_experts(h: jax.Array, gates, idx, wg, wu, wd, mo,
+                  layer=None) -> tuple:
+    """The held experts' part of the result for the tokens [T,H] routed
+    to them, dropless: assignments sorted by expert, one ragged matmul
+    per projection over the held assignments only.  Returns that part
+    and the tokens routed to each held expert.
+
+    With ``layer`` the expert weights are the stack of all MoE layers
+    [layers, held, ...] and this is layer ``layer`` of it: the ragged
+    matmuls take the whole stack with the other layers' groups empty,
+    so no layer's experts are sliced out of it (a copy) first."""
+    T, K = idx.shape
+    local, counts = _held(idx, mo)
+    groups, sizes = mo.held, counts
+    if layer is not None:
+        groups = wg.shape[0] * mo.held
+        wg, wu, wd = (w.reshape((groups,) + w.shape[2:]) for w in (wg, wu, wd))
+        local = jnp.where(local < mo.held, local + layer * mo.held, groups)
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(groups, jnp.int32), counts, (layer * mo.held,))
+    order = jnp.argsort(local)
+    grp, tok = local[order], order // K
+    xs = h[tok]                                          # [T*K, H]
+    act = jax.nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) \
+        * jax.lax.ragged_dot(xs, wu, sizes)
+    eo = jax.lax.ragged_dot(act, wd, sizes)
+    g = gates.reshape(-1)[order].astype(h.dtype)
+    eo = jnp.where((grp < groups)[:, None], eo * g[:, None], 0)
+    return jnp.zeros_like(h).at[tok].add(eo), counts
+
+
+def _route_and_compute(h, wr, wg, wu, wd, *, mo, capacity_factor: float,
+                       a2a_axis: str, gather_axes: tuple = (),
+                       count_axes: tuple = ()):
+    """Expert-parallel block, run under ``shard_map``: local routing,
+    capacity dispatch of the held experts' assignments, the explicit
+    ``jax.lax.all_to_all`` pair over the expert axis (the EP pattern the
+    STG matcher predicts, Table IV), the expert matmuls on this shard's
+    slice [E_loc, H, F] of the held experts, and the combine.  Returns
+    the result for this shard's tokens [b_loc, s, H] and the tokens
+    routed to each held expert over all shards (``count_axes``: the mesh
+    axes the tokens are split over)."""
     b, s, H = h.shape
     if gather_axes:
         # expert weights stored ZeRO-3-sharded over the data axes; gather
@@ -433,14 +566,14 @@ def _route_and_compute(h, wr, wg, wu, wd, *, E: int, Kk: int,
         wg = jax.lax.all_gather(wg, gather_axes, axis=1, tiled=True)
         wu = jax.lax.all_gather(wu, gather_axes, axis=1, tiled=True)
         wd = jax.lax.all_gather(wd, gather_axes, axis=1, tiled=True)
-    logits = jnp.einsum("bsh,he->bse", h.astype(jnp.float32), wr)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, Kk)
-    gates = (gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)).astype(h.dtype)
+    T, Kk, E = b * s, mo.top_k, mo.held
+    gates, idx = route(h.reshape(T, H), wr, mo)
+    gates = gates.astype(h.dtype)
+    flat_idx, counts = _held(idx, mo)
+    if count_axes:
+        counts = jax.lax.psum(counts, count_axes)
 
-    T = b * s
     C = max(1, int(math.ceil(T * Kk / E * capacity_factor)))
-    flat_idx = idx.reshape(T * Kk)
     flat_tok = jnp.repeat(jnp.arange(T), Kk)
     order = jnp.argsort(flat_idx)
     se, st = flat_idx[order], flat_tok[order]
@@ -449,66 +582,71 @@ def _route_and_compute(h, wr, wg, wu, wd, *, E: int, Kk: int,
     seg_start = jnp.where(same == 0, jnp.arange(T * Kk), 0)
     seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
     rank = jnp.arange(T * Kk) - seg_start
-    keep = rank < C
+    keep = (rank < C) & (se < E)
     hx = h.reshape(T, H)
     dispatched = jnp.zeros((E, C, H), h.dtype)
     dispatched = dispatched.at[jnp.where(keep, se, 0),
                                jnp.where(keep, rank, 0)].add(
         jnp.where(keep[:, None], hx[st], 0))
 
-    if a2a_axis is not None:
-        ep = jax.lax.axis_size(a2a_axis)
-        e_loc = E // ep
-        # send each expert-group's tokens to its owner; receive everyone's
-        d4 = dispatched.reshape(ep, e_loc, C, H)
-        d4 = jax.lax.all_to_all(d4, a2a_axis, split_axis=0, concat_axis=2,
-                                tiled=True)
-        dispatched = d4.reshape(e_loc, ep * C, H)
+    ep = jax.lax.axis_size(a2a_axis)
+    e_loc = E // ep
+    # send each expert-group's tokens to its owner; receive everyone's
+    d4 = dispatched.reshape(ep, e_loc, C, H)
+    d4 = jax.lax.all_to_all(d4, a2a_axis, split_axis=0, concat_axis=2,
+                            tiled=True)
+    dispatched = d4.reshape(e_loc, ep * C, H)
 
     eg = jnp.einsum("ech,ehf->ecf", dispatched, wg)
     eu = jnp.einsum("ech,ehf->ecf", dispatched, wu)
     ea = jax.nn.silu(eg) * eu
     eo = jnp.einsum("ecf,efh->ech", ea, wd)
 
-    if a2a_axis is not None:
-        ep = jax.lax.axis_size(a2a_axis)
-        e_loc = E // ep
-        y4 = eo.reshape(e_loc, ep, C, H)
-        y4 = jax.lax.all_to_all(y4, a2a_axis, split_axis=1, concat_axis=0,
-                                tiled=True)
-        eo = y4.reshape(E, C, H)
+    y4 = eo.reshape(e_loc, ep, C, H)
+    y4 = jax.lax.all_to_all(y4, a2a_axis, split_axis=1, concat_axis=0,
+                            tiled=True)
+    eo = y4.reshape(E, C, H)
 
     flat_gate = gates.reshape(T * Kk)[order]
     token_out = jnp.zeros((T, H), h.dtype)
     token_out = token_out.at[st].add(
-        jnp.where(keep[:, None], eo[se, jnp.minimum(rank, C - 1)]
+        jnp.where(keep[:, None], eo[jnp.minimum(se, E - 1),
+                                    jnp.minimum(rank, C - 1)]
                   * flat_gate[:, None], 0))
-    return token_out.reshape(b, s, H)
+    return token_out.reshape(b, s, H), counts
 
 
 @scoped
 def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
-            rules: Optional[AxisRules], *, capacity_factor: float = 0.0) -> jax.Array:
-    """Sort-based top-k MoE with static expert capacity.
+            rules: Optional[AxisRules], *, capacity_factor: float = 0.0,
+            with_counts: bool = False, layer=None):
+    """Routed experts (``route``) plus the shared experts once.
 
-    With a mesh attached to ``rules`` the block runs under ``shard_map``:
-    tokens stay local to their data shard, experts are sharded over the
-    expert (model) axis, and dispatch/combine are explicit AllToAlls —
-    the production EP pattern (and the one the STG matcher emits)."""
+    On one device the held experts' product is dropless
+    (``_held_experts``).  With a mesh attached to ``rules`` the block
+    runs under ``shard_map``: tokens stay local to their data shard,
+    the held experts are sharded over the expert (model) axis with a
+    static capacity per expert, and dispatch/combine are explicit
+    AllToAlls — the production EP pattern (and the one the STG matcher
+    emits).  ``with_counts`` also returns the tokens routed to each held
+    expert [held] (int32).  ``layer``: the expert weights in ``p`` are
+    the stack of all MoE layers and this is layer ``layer`` of it."""
     mo = spec.moe
     capacity_factor = capacity_factor or rt.moe_capacity
     b, s, H = x.shape
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
     wr = p["w_router"].value
-    wg, wu, wd = (cast(p[k].value, rt) for k in ("w_egate", "w_eup", "w_edown"))
+    wg, wu, wd = (cast(p[k].value, rt) for k in EXPERT_WEIGHTS)
 
     mesh = getattr(rules, "mesh", None) if rules is not None else None
     ep_axis = rules.rules.get("experts") if rules is not None else None
     if mesh is not None and ep_axis in getattr(mesh, "shape", {}) \
-            and mo.n_experts % mesh.shape[ep_axis] == 0 \
+            and mo.held % mesh.shape[ep_axis] == 0 \
             and mesh.shape[ep_axis] > 1:
         from jax.sharding import PartitionSpec as P
+        if layer is not None:
+            wg, wu, wd = wg[layer], wu[layer], wd[layer]
         from jax import shard_map
         da = rules.rules.get("act_batch") or ()
         da = tuple(a for a in (da if isinstance(da, (tuple, list)) else (da,))
@@ -518,11 +656,11 @@ def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         # tokens: batch over data axes; sequence over the expert axis too
         # (otherwise every expert-axis peer routes identical tokens)
         if da and b % deg == 0 and s % ep == 0 and s > 1:
-            bspec = P(da, ep_axis)
+            bspec, count_axes = P(da, ep_axis), da + (ep_axis,)
         elif da and b % deg == 0:
-            bspec = P(da)
+            bspec, count_axes = P(da), da
         else:
-            bspec = P()
+            bspec, count_axes = P(), ()
         # expert weights: experts over the ep axis + ZeRO-3 over data axes
         gather = da if all(w.shape[1] % deg == 0
                            for w in (wg, wu)) and da else ()
@@ -530,26 +668,33 @@ def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         if gather:
             wg = jax.lax.with_sharding_constraint(
                 wg, jax.sharding.NamedSharding(mesh, wspec))
-        fn = shard_map(
-            functools.partial(_route_and_compute, E=mo.n_experts,
-                              Kk=mo.top_k, capacity_factor=capacity_factor,
-                              a2a_axis=ep_axis, gather_axes=gather),
-            mesh=mesh,
-            in_specs=(bspec, P(), wspec, wspec, wspec),
-            out_specs=bspec, check_vma=False)
-        out = fn(h, wr, wg, wu, wd)
+        with jax.named_scope("moe_experts"):
+            fn = shard_map(
+                functools.partial(_route_and_compute, mo=mo,
+                                  capacity_factor=capacity_factor,
+                                  a2a_axis=ep_axis, gather_axes=gather,
+                                  count_axes=count_axes),
+                mesh=mesh,
+                in_specs=(bspec, P(), wspec, wspec, wspec),
+                out_specs=(bspec, P()), check_vma=False)
+            out, counts = fn(h, wr, wg, wu, wd)
     else:
-        out = _route_and_compute(h, wr, wg, wu, wd, E=mo.n_experts,
-                                 Kk=mo.top_k,
-                                 capacity_factor=capacity_factor,
-                                 a2a_axis=None)
+        hx = h.reshape(b * s, H)
+        with jax.named_scope("moe_route"):
+            gates, idx = route(hx, wr, mo)
+        with jax.named_scope("moe_experts"):
+            out, counts = _held_experts(hx, gates, idx, wg, wu, wd, mo,
+                                        layer)
+        out = out.reshape(b, s, H)
     if "shared" in p:
-        hs = jnp.einsum("bsh,hf->bsf", h, cast(p["shared"]["w_gate"].value, rt))
-        hu = jnp.einsum("bsh,hf->bsf", h, cast(p["shared"]["w_up"].value, rt))
-        so = jnp.einsum("bsf,fh->bsh", jax.nn.silu(hs) * hu,
-                        cast(p["shared"]["w_down"].value, rt))
+        with jax.named_scope("moe_shared"):
+            hs = jnp.einsum("bsh,hf->bsf", h, cast(p["shared"]["w_gate"].value, rt))
+            hu = jnp.einsum("bsh,hf->bsf", h, cast(p["shared"]["w_up"].value, rt))
+            so = jnp.einsum("bsf,fh->bsh", jax.nn.silu(hs) * hu,
+                            cast(p["shared"]["w_down"].value, rt))
         out = out + so
-    return x + constrain(out, rules, (BATCH, SEQ, EMB))
+    out = x + constrain(out, rules, (BATCH, SEQ, EMB))
+    return (out, counts) if with_counts else out
 
 
 # ---------------------------------------------------------------------------

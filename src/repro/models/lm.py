@@ -11,7 +11,7 @@ API:
   forward(params, spec, rt, rules, ...)  -> logits  (train / prefill)
   loss_fn(params, batch, ...)            -> scalar
   init_cache(spec, rt, batch, kv_len)    -> decode cache
-  decode_step(params, cache, tokens,...) -> (logits, cache)
+  decode_step(params, cache, tokens,...) -> (logits, cache[, routed])
 """
 from __future__ import annotations
 
@@ -148,8 +148,13 @@ def _index(tree, i):
 
 def _apply_slot(p: dict, x, spec, rt, rules, kind: dict, *,
                 positions=None, cache=None, cross_kv=None, cross_p=None,
-                cross_cache=None):
+                cross_cache=None, moe_layer=None):
+    """One layer: (x, its new cache or None, the tokens routed to each
+    held expert [held] for an MoE layer, else None).  ``moe_layer``: the
+    expert weights in ``p`` are the stack of the slot's layers and this
+    is layer ``moe_layer`` of it (``layers.moe_ffn``)."""
     new_cache: dict = {}
+    routed = None
     if kind["mixer"] == "attn":
         if spec.block == "mla":
             x, c = L.mla_attention(p["attn"], x, spec, rt, rules,
@@ -177,10 +182,11 @@ def _apply_slot(p: dict, x, spec, rt, rules, kind: dict, *,
         if cache is not None and cc is not None:
             new_cache["cross"] = cc
     if kind["ffn"] == "moe":
-        x = L.moe_ffn(p["moe"], x, spec, rt, rules)
+        x, routed = L.moe_ffn(p["moe"], x, spec, rt, rules, with_counts=True,
+                              layer=moe_layer)
     elif kind["ffn"] == "ffn":
         x = L.ffn(p["ffn"], x, spec, rt, rules)
-    return x, (new_cache or None)
+    return x, (new_cache or None), routed
 
 
 def _remat(fn, rt: RuntimeCfg):
@@ -227,9 +233,8 @@ def forward(params: dict, tokens, spec, rt: RuntimeCfg,
         kind = _slot_kind(spec, layer_idx)
 
         def prefix_block(xc, pc, kind=kind):
-            h, _ = _apply_slot(pc, xc, spec, rt, rules, kind,
-                               positions=positions)
-            return h
+            return _apply_slot(pc, xc, spec, rt, rules, kind,
+                               positions=positions)[0]
         x = _remat(prefix_block, rt)(x, p)
         layer_idx += 1
 
@@ -239,10 +244,10 @@ def forward(params: dict, tokens, spec, rt: RuntimeCfg,
         def group(xc, slot_params):
             h = xc
             for s in range(period):
-                h, _ = _apply_slot(slot_params[s], h, spec, rt, rules, kinds[s],
-                                   positions=positions,
-                                   cross_kv=cross_kv,
-                                   cross_p=slot_params[period] if spec.encoder_layers else None)
+                h = _apply_slot(slot_params[s], h, spec, rt, rules, kinds[s],
+                                positions=positions,
+                                cross_kv=cross_kv,
+                                cross_p=slot_params[period] if spec.encoder_layers else None)[0]
             return h, None
 
         scanned = list(params["slots"])
@@ -350,17 +355,24 @@ def init_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
 
 
 def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
-                rules: Optional[AxisRules] = None) -> tuple[jax.Array, dict]:
-    """One decode step: tokens [B, 1] -> (logits [B,1,V], new cache)."""
+                rules: Optional[AxisRules] = None, *,
+                routed: bool = False) -> tuple:
+    """One decode step: tokens [B, 1] -> (logits [B,1,V], new cache).
+    With ``routed`` also the tokens of all B rows routed to each held
+    expert of each MoE layer, in layer order: [MoE layers, held] int32
+    (None for a model without MoE layers)."""
     with jax.named_scope("embed"):
         x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
     prefix_n, period = layer_pattern(spec)
     new_cache = {"prefix": [], "slots": []}
+    counts = []            # (layer index, [held]) of each MoE layer
     li = 0
     for p, c in zip(params["prefix"], cache["prefix"]):
         kind = _slot_kind(spec, li)
-        x, nc = _apply_slot(p, x, spec, rt, rules, kind, cache=c)
+        x, nc, r = _apply_slot(p, x, spec, rt, rules, kind, cache=c)
         new_cache["prefix"].append(nc)
+        if r is not None:
+            counts.append((li, r))
         li += 1
 
     kinds = [_slot_kind(spec, prefix_n + s) for s in range(period)]
@@ -368,19 +380,33 @@ def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
         if not params["slots"][s]:
             new_cache["slots"].append({})
             continue
+        ps, experts = params["slots"][s], None
+        if kinds[s]["ffn"] == "moe":
+            # the scan reads the experts from the whole stack: no layer's
+            # experts are sliced out of it each step
+            moe = dict(ps["moe"])
+            experts = {k: moe.pop(k) for k in L.EXPERT_WEIGHTS}
+            ps = {**ps, "moe": moe}
 
-        def step(xc, pc_cc):
-            pc, cc = pc_cc[0], pc_cc[1]
-            cross_p = pc_cc[2] if spec.encoder_layers else None
-            h, nc = _apply_slot(pc, xc, spec, rt, rules, kinds[s],
-                                cache=cc, cross_p=cross_p,
-                                cross_cache=cc.get("cross") if cc else None)
-            return h, nc
+        def step(xc, pc_cc, s=s, experts=experts):
+            pc, cc, i = pc_cc[0], pc_cc[1], pc_cc[2]
+            cross_p = pc_cc[3] if spec.encoder_layers else None
+            if experts is not None:
+                pc = {**pc, "moe": {**pc["moe"], **experts}}
+            h, nc, r = _apply_slot(pc, xc, spec, rt, rules, kinds[s],
+                                   cache=cc, cross_p=cross_p,
+                                   cross_cache=cc.get("cross") if cc else None,
+                                   moe_layer=None if experts is None else i)
+            return h, (nc, r)
 
-        scanned = (params["slots"][s], cache["slots"][s]) + \
+        n_rep = jax.tree.leaves(cache["slots"][s])[0].shape[0]
+        scanned = (ps, cache["slots"][s], jnp.arange(n_rep)) + \
             ((params["cross"],) if spec.encoder_layers else ())
-        x, ncs = jax.lax.scan(step, x, scanned)
+        x, (ncs, rs) = jax.lax.scan(step, x, scanned)
         new_cache["slots"].append(ncs)
+        if rs is not None:
+            counts += [(prefix_n + s + i * period, rs[i])
+                       for i in range(rs.shape[0])]
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(params["ln_f"], x)
@@ -388,4 +414,8 @@ def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
             dt(rt.compute_dtype)))
         if spec.final_softcap:
             logits = L._softcap(logits.astype(jnp.float32), spec.final_softcap)
-    return logits, new_cache
+    if not routed:
+        return logits, new_cache
+    counts = jnp.stack([r for _, r in sorted(counts, key=lambda c: c[0])]) \
+        if counts else None
+    return logits, new_cache, counts

@@ -28,6 +28,11 @@ token).  Counters: ``engine.steps``, ``engine.requests_admitted``,
 ``engine.prompt_tokens_admitted``, ``engine.tokens_out``,
 ``engine.rows_prefill``.  Each request keeps its own times
 (``t_submit``, ``t_admit``, ``t_first``; perf_counter) under its ``rid``.
+For a model with MoE layers the step also counts, on the device, the
+tokens of all rows routed to each held expert of each MoE layer; the
+host reads them with the sampled tokens, in one read, into the histogram
+``moe.expert_tokens`` (their mean, tokens per held expert per layer, one
+sample per step) and the counter ``moe.routed_tokens`` (their sum).
 """
 from __future__ import annotations
 
@@ -56,9 +61,13 @@ class Request:
     t_first: Optional[float] = None
 
 
-def make_serve_step(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None):
+def make_serve_step(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None,
+                    *, routed: bool = False):
+    """(params, cache, tokens) -> (logits, cache), and with ``routed``
+    the tokens routed to each held expert (``lm.decode_step``)."""
     def serve_step(params, cache, tokens):
-        return lm.decode_step(params, cache, tokens, spec, rt, rules)
+        return lm.decode_step(params, cache, tokens, spec, rt, rules,
+                              routed=routed)
     return serve_step
 
 
@@ -80,7 +89,9 @@ class Engine:
         self.kv_len = kv_len
         self.slots: list[Optional[Request]] = [None] * batch_slots
         self.cache = lm.init_cache(spec, rt, batch_slots, kv_len)
-        self.step_fn = jax.jit(make_serve_step(spec, rt, rules))
+        self.routed = spec.moe is not None
+        self.step_fn = jax.jit(make_serve_step(spec, rt, rules,
+                                               routed=self.routed))
         self.queue: list[Request] = []
         self.n_steps = 0
         self._sampled: Optional[float] = None   # end of the last sample
@@ -140,11 +151,20 @@ class Engine:
                 st.set(rows=live)
                 t_dispatch = time.perf_counter()
                 with span("engine.dispatch"):
-                    logits, self.cache = self.step_fn(self.params, self.cache,
-                                                      jnp.asarray(tok_host))
+                    out = self.step_fn(self.params, self.cache,
+                                       jnp.asarray(tok_host))
+                    logits, self.cache = out[0], out[1]
                 with span("engine.sample"):
-                    nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+                    nxt = jnp.argmax(logits[:, 0], axis=-1)
+                    if self.routed:
+                        nxt, routed = jax.device_get((nxt, out[2]))
+                    else:
+                        nxt = np.asarray(nxt)
                 t_sampled = time.perf_counter()
+                if self.routed:
+                    metrics.histogram("moe.expert_tokens").observe(
+                        float(routed.mean()))
+                    metrics.counter("moe.routed_tokens").inc(int(routed.sum()))
                 useful = 0
                 for i, req in enumerate(self.slots):
                     if req is None:

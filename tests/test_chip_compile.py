@@ -11,6 +11,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a module that touched it at
 collection would break the other test workers.
 """
+import dataclasses
 import os
 
 import jax
@@ -113,6 +114,28 @@ def test_granite_34b_serve_step_fits_one_chip(one_chip):
     tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
     compiled = jax.jit(make_serve_step(spec, rt)).lower(
         params, cache, tok).compile()
+    assert _hbm_bytes(compiled) <= HBM
+
+
+def test_deepseek_v2_serve_step_fits_one_chip(one_chip):
+    """The benchmark's cut: the dense layer and 4 MoE layers at published
+    widths, each holding 20 of the 160 experts; 128 slots x 1536 latent
+    positions.  Attention reads the latent cache and forms no per-head
+    key or value of it; the held experts go through the ragged dot."""
+    spec = cut_depth(get("deepseek-v2-236b").spec, 5)
+    spec = dataclasses.replace(spec, moe=dataclasses.replace(spec.moe,
+                                                             n_held=20))
+    rt = RuntimeCfg(attention_impl="naive")
+    params = _on(one_chip, jax.eval_shape(
+        lambda: init_params(spec, rt, jax.random.PRNGKey(0))))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_cache(spec, rt, 128, 1536)))
+    tok = jax.ShapeDtypeStruct((128, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(spec, rt, routed=True)).lower(
+        params, cache, tok).compile()
+    text = compiled.as_text().replace(" ", "")
+    assert not [d for d in (128, 192, 256) if f"[128,1536,128,{d}]" in text]
+    assert "ragged-dot" in text
     assert _hbm_bytes(compiled) <= HBM
 
 
