@@ -252,6 +252,45 @@ def attn_core(q, k, v, rt: RuntimeCfg, *, causal: bool, window=None,
 
 
 # ---------------------------------------------------------------------------
+# Decode caches written by position
+# ---------------------------------------------------------------------------
+
+def cache_pos(cache: dict, layer=None) -> jax.Array:
+    """The next position to write in ``cache`` (of layer ``layer`` of a
+    stacked cache)."""
+    return cache["pos"] if layer is None else cache["pos"][layer]
+
+
+def write_cache(cache: dict, new: dict, pos, axis: int,
+                layer=None) -> tuple[dict, dict]:
+    """Write each ``new[k]`` into ``cache[k]`` from position ``pos`` along
+    ``axis`` of one layer's array, and advance the position.  Returns (the
+    cache to hand on, this layer's arrays after the write).
+
+    With ``layer``, ``cache`` is the stack of a scanned slot's layers (a
+    leading layer axis, one ``pos`` per layer), carried through the scan:
+    each write is one ``dynamic_update_slice`` at (``layer``, ``pos``),
+    which XLA makes in place in a donated or carried buffer, and the layer
+    is read back from the updated stack."""
+    n = next(iter(new.values())).shape[axis]
+    if layer is None:
+        out = {k: jax.lax.dynamic_update_slice_in_dim(cache[k], v, pos, axis)
+               for k, v in new.items()}
+        return {**out, "pos": pos + n}, out
+    out = {}
+    for k, v in new.items():
+        start = [0] * cache[k].ndim
+        start[0], start[1 + axis] = layer, pos
+        out[k] = jax.lax.dynamic_update_slice(cache[k], v[None], start)
+    # one copy of the layer for all of its products: XLA would otherwise
+    # slice it out of the stack once for each
+    mine = jax.lax.optimization_barrier(
+        {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+         for k, a in out.items()})
+    return {**out, "pos": cache["pos"].at[layer].add(n)}, mine
+
+
+# ---------------------------------------------------------------------------
 # GQA attention layer (granite/gemma2/qwen3/minitron/whisper/internvl/jamba)
 # ---------------------------------------------------------------------------
 
@@ -278,7 +317,11 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
                   window: Optional[int] = None, causal: bool = True,
                   cross_kv: Optional[jax.Array] = None,
-                  cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+                  cache: Optional[dict] = None,
+                  layer=None) -> tuple[jax.Array, Optional[dict]]:
+    """Grouped-query attention.  The self-attention cache holds ``k``
+    and ``v`` [B,T,NKV,DH]; with ``layer`` it is the stack of a scanned
+    slot's layers and this is layer ``layer`` of it (``write_cache``)."""
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
     q = jnp.einsum("bsh,hngd->bsngd", h, cast(p["w_q"].value, rt))
@@ -291,7 +334,7 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         v_new = jnp.einsum("bsh,hnd->bsnd", h, cast(p["w_v"].value, rt))
         if p.get("kn") is not None:
             k_new = rms_norm(p["kn"], k_new)
-        pos = cache["pos"]
+        pos = cache_pos(cache, layer)
         if positions is None:
             positions = pos + jnp.zeros(x.shape[:2], jnp.int32)
         k_new = rope(k_new, positions)
@@ -313,10 +356,9 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
             pr = jax.nn.softmax(s, axis=-1).astype(q.dtype)
             out5 = jnp.einsum("bngsk,bknd->bsngd", pr, v)
         else:
-            k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, pos, axis=1)
-            v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, pos, axis=1)
-            new_cache = {"k": k, "v": v, "pos": pos + s_new}
-            out5 = attn_core(q, k, v, rt, causal=True, window=window,
+            new_cache, kv = write_cache(cache, {"k": k_new, "v": v_new}, pos,
+                                        1, layer)
+            out5 = attn_core(q, kv["k"], kv["v"], rt, causal=True, window=window,
                              softcap=spec.attn_softcap, q_offset=pos)
     elif cache is not None:                      # cached cross-attn (k/v only)
         k, v = cache["k"], cache["v"]
@@ -366,11 +408,14 @@ def init_mla(ini: Initializer, spec, prefix: str = "") -> dict:
 @scoped
 def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
-                  cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+                  cache: Optional[dict] = None,
+                  layer=None) -> tuple[jax.Array, Optional[dict]]:
     """Multi-head latent attention.  The cache holds the normed latent
-    ``ckv`` [B,T,kv_lora] and the rotated ``kr`` [B,T,rope_dim].  With a
-    cache, attention runs in the latent space (``mla_decode``); without
-    one (prefill, training) keys and values are expanded per head."""
+    ``ckv`` [T,B,kv_lora] and the rotated ``kr`` [T,B,rope_dim], time
+    major, the layout the latent products read; with ``layer`` it is the
+    stack of a scanned slot's layers (``write_cache``).  With a cache,
+    attention runs in the latent space (``mla_decode``); without one
+    (prefill, training) keys and values are expanded per head."""
     m = spec.mla
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
@@ -380,7 +425,7 @@ def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
 
     ckv_new = rms_norm(p["ln_kv"], jnp.einsum("bsh,hr->bsr", h, cast(p["w_dkv"].value, rt)))
     kr_new = jnp.einsum("bsh,hd->bsd", h, cast(p["w_kr"].value, rt))
-    pos = cache["pos"] if cache is not None else 0
+    pos = cache_pos(cache, layer) if cache is not None else 0
     if positions is None:
         positions = pos + jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
     inv_freq = mla_rope(m)
@@ -388,11 +433,11 @@ def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     kr_new = rope(kr_new[:, :, None], positions, inv_freq=inv_freq)[:, :, 0]
     scale = mla_softmax_scale(m)
     if cache is not None:
-        ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_new, pos, axis=1)
-        kr = jax.lax.dynamic_update_slice_in_dim(cache["kr"], kr_new, pos, axis=1)
-        new_cache = {"ckv": ckv, "kr": kr, "pos": pos + x.shape[1]}
+        new_cache, lat = write_cache(
+            cache, {"ckv": ckv_new.transpose(1, 0, 2),
+                    "kr": kr_new.transpose(1, 0, 2)}, pos, 0, layer)
         with jax.named_scope("mla_decode"):
-            ctx = _mla_latent(p, qn, qr, ckv, kr, pos, scale, rt)
+            ctx = _mla_latent(p, qn, qr, lat["ckv"], lat["kr"], pos, scale, rt)
     else:
         kn = jnp.einsum("btr,rnd->btnd", ckv_new, cast(p["w_uk"].value, rt))
         vv = jnp.einsum("btr,rnd->btnd", ckv_new, cast(p["w_uv"].value, rt))
@@ -411,17 +456,20 @@ def _mla_latent(p: dict, qn, qr, ckv, kr, pos, scale: float,
                 rt: RuntimeCfg) -> jax.Array:
     """Attention against the latent cache: the query absorbs ``w_uk``,
     scores are (q_nope W_uk) . ckv + q_rope . kr, the weighted sum of
-    ``ckv`` goes through ``w_uv`` after.  ckv [B,T,R] and kr [B,T,Dr]
-    are read once; no per-head key or value is formed.  Positions past
-    ``pos`` + the query's own are masked."""
+    ``ckv`` goes through ``w_uv`` after.  ckv [T,B,R] and kr [T,B,Dr]
+    (time major) are read once; no per-head key or value is formed.
+    Positions past ``pos`` + the query's own are masked."""
     q_lat = jnp.einsum("bsnd,rnd->bsnr", qn, cast(p["w_uk"].value, rt))
     f32 = jnp.float32
-    s = (jnp.einsum("bsnr,btr->bnst", q_lat, ckv, preferred_element_type=f32)
-         + jnp.einsum("bsnd,btd->bnst", qr, kr, preferred_element_type=f32)) * scale
+    # the cache is the scores' first operand, so that both products read
+    # it in its own layout (R minor) and the step relays none of it out
+    s = (jnp.einsum("tbr,bsnr->bstn", ckv, q_lat, preferred_element_type=f32)
+         + jnp.einsum("tbd,bsnd->bstn", kr, qr, preferred_element_type=f32)) * scale
     qpos = pos + jnp.arange(qn.shape[1])
-    s = jnp.where(jnp.arange(ckv.shape[1])[None, :] <= qpos[:, None], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1).astype(ckv.dtype)
-    ctx = jnp.einsum("bnst,btr->bsnr", pr, ckv)
+    seen = jnp.arange(ckv.shape[0])[None, :] <= qpos[:, None]       # [S, T]
+    s = jnp.where(seen[:, :, None], s, -1e30)
+    pr = jax.nn.softmax(s, axis=2).astype(ckv.dtype)
+    ctx = jnp.einsum("bstn,tbr->bsnr", pr, ckv)
     return jnp.einsum("bsnr,rnd->bsnd", ctx, cast(p["w_uv"].value, rt))
 
 

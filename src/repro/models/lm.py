@@ -148,21 +148,23 @@ def _index(tree, i):
 
 def _apply_slot(p: dict, x, spec, rt, rules, kind: dict, *,
                 positions=None, cache=None, cross_kv=None, cross_p=None,
-                cross_cache=None, moe_layer=None):
+                cross_cache=None, moe_layer=None, layer=None):
     """One layer: (x, its new cache or None, the tokens routed to each
     held expert [held] for an MoE layer, else None).  ``moe_layer``: the
     expert weights in ``p`` are the stack of the slot's layers and this
-    is layer ``moe_layer`` of it (``layers.moe_ffn``)."""
+    is layer ``moe_layer`` of it (``layers.moe_ffn``); ``layer``: so is
+    the attention cache (``layers.write_cache``)."""
     new_cache: dict = {}
     routed = None
     if kind["mixer"] == "attn":
         if spec.block == "mla":
             x, c = L.mla_attention(p["attn"], x, spec, rt, rules,
-                                   positions=positions,
+                                   positions=positions, layer=layer,
                                    cache=None if cache is None else cache.get("attn"))
         else:
             x, c = L.gqa_attention(p["attn"], x, spec, rt, rules,
                                    positions=positions, window=kind["window"],
+                                   layer=layer,
                                    cache=None if cache is None else cache.get("attn"))
         if c is not None:
             new_cache["attn"] = c
@@ -310,10 +312,10 @@ def _slot_cache(spec, rt, kind: dict, batch: int, kv_len: int) -> dict:
     cdt = dt(rt.compute_dtype)
     c: dict = {}
     if kind["mixer"] == "attn":
-        if spec.block == "mla":
+        if spec.block == "mla":                  # time major: [T, B, ...]
             m = spec.mla
-            c["attn"] = {"ckv": jnp.zeros((batch, kv_len, m.kv_lora), cdt),
-                         "kr": jnp.zeros((batch, kv_len, m.rope_dim), cdt),
+            c["attn"] = {"ckv": jnp.zeros((kv_len, batch, m.kv_lora), cdt),
+                         "kr": jnp.zeros((kv_len, batch, m.rope_dim), cdt),
                          "pos": jnp.zeros((), jnp.int32)}
         else:
             nkv, dh = max(1, spec.n_kv_heads), spec.head_dim
@@ -354,13 +356,27 @@ def init_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
     return cache
 
 
+def _in_place(kind: dict) -> bool:
+    """Whether a slot's layers write their attention cache by position
+    (full attention, GQA or MLA), so that ``decode_step`` can carry the
+    slot's cache stack through the layer scan.  Sliding-window ring
+    caches, mamba and rwkv states are rewritten whole."""
+    return kind["mixer"] == "attn" and kind["window"] is None
+
+
 def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
                 rules: Optional[AxisRules] = None, *,
                 routed: bool = False) -> tuple:
     """One decode step: tokens [B, 1] -> (logits [B,1,V], new cache).
     With ``routed`` also the tokens of all B rows routed to each held
     expert of each MoE layer, in layer order: [MoE layers, held] int32
-    (None for a model without MoE layers)."""
+    (None for a model without MoE layers).
+
+    A scanned slot whose layers write their attention cache by position
+    carries that cache's stack through the scan, and each layer writes
+    its new positions into it; with the cache donated, as ``Engine``
+    does, the whole cache is then updated in place.  Other caches go
+    through the scan as its inputs and outputs."""
     with jax.named_scope("embed"):
         x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
     prefix_n, period = layer_pattern(spec)
@@ -375,38 +391,56 @@ def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
             counts.append((li, r))
         li += 1
 
+    n_rep = (spec.n_layers - prefix_n) // period
     kinds = [_slot_kind(spec, prefix_n + s) for s in range(period)]
+    ps, experts = list(params["slots"]), [None] * period
     for s in range(period):
-        if not params["slots"][s]:
-            new_cache["slots"].append({})
-            continue
-        ps, experts = params["slots"][s], None
-        if kinds[s]["ffn"] == "moe":
+        if kinds[s]["ffn"] == "moe" and n_rep:
             # the scan reads the experts from the whole stack: no layer's
             # experts are sliced out of it each step
-            moe = dict(ps["moe"])
-            experts = {k: moe.pop(k) for k in L.EXPERT_WEIGHTS}
-            ps = {**ps, "moe": moe}
+            moe = dict(ps[s]["moe"])
+            experts[s] = {k: moe.pop(k) for k in L.EXPERT_WEIGHTS}
+            ps[s] = {**ps[s], "moe": moe}
+    stacks = [c.get("attn") if _in_place(k) else None
+              for c, k in zip(cache["slots"], kinds)]
+    rest = [{k: v for k, v in c.items() if st is None or k != "attn"}
+            for c, st in zip(cache["slots"], stacks)]
 
-        def step(xc, pc_cc, s=s, experts=experts):
-            pc, cc, i = pc_cc[0], pc_cc[1], pc_cc[2]
-            cross_p = pc_cc[3] if spec.encoder_layers else None
-            if experts is not None:
-                pc = {**pc, "moe": {**pc["moe"], **experts}}
-            h, nc, r = _apply_slot(pc, xc, spec, rt, rules, kinds[s],
-                                   cache=cc, cross_p=cross_p,
-                                   cross_cache=cc.get("cross") if cc else None,
-                                   moe_layer=None if experts is None else i)
-            return h, (nc, r)
+    def group(carry, xs):
+        """Layers ``prefix_n + i * period + s`` for each slot ``s``."""
+        (h, stacks), (pcs, ccs, i) = carry, xs[:3]
+        cross_p = xs[3] if spec.encoder_layers else None
+        stacks, ncs, rs = list(stacks), [], []
+        for s in range(period):
+            pc, cc = pcs[s], ccs[s]
+            if experts[s] is not None:
+                pc = {**pc, "moe": {**pc["moe"], **experts[s]}}
+            if stacks[s] is not None:
+                cc = {**cc, "attn": stacks[s]}
+            h, nc, r = _apply_slot(
+                pc, h, spec, rt, rules, kinds[s], cache=cc, cross_p=cross_p,
+                cross_cache=cc.get("cross"),
+                moe_layer=None if experts[s] is None else i,
+                layer=None if stacks[s] is None else i)
+            if stacks[s] is not None:
+                nc = dict(nc)
+                stacks[s] = nc.pop("attn")
+            ncs.append(nc)
+            rs.append(r)
+        return (h, stacks), (ncs, rs)
 
-        n_rep = jax.tree.leaves(cache["slots"][s])[0].shape[0]
-        scanned = (ps, cache["slots"][s], jnp.arange(n_rep)) + \
+    if n_rep:
+        scanned = (ps, rest, jnp.arange(n_rep)) + \
             ((params["cross"],) if spec.encoder_layers else ())
-        x, (ncs, rs) = jax.lax.scan(step, x, scanned)
-        new_cache["slots"].append(ncs)
-        if rs is not None:
-            counts += [(prefix_n + s + i * period, rs[i])
-                       for i in range(rs.shape[0])]
+        (x, stacks), (ncs, rs) = jax.lax.scan(group, (x, stacks), scanned)
+        new_cache["slots"] = [nc if st is None else {"attn": st, **nc}
+                              for nc, st in zip(ncs, stacks)]
+        for s, r in enumerate(rs):
+            if r is not None:
+                counts += [(prefix_n + s + i * period, r[i])
+                           for i in range(n_rep)]
+    else:
+        new_cache["slots"] = cache["slots"]
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(params["ln_f"], x)

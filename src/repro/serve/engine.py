@@ -26,7 +26,11 @@ after the engine's first (end of the previous ``engine.sample`` to this
 ``engine.ttft_s`` per request (submit to the host's read of its first
 token).  Counters: ``engine.steps``, ``engine.requests_admitted``,
 ``engine.prompt_tokens_admitted``, ``engine.tokens_out``,
-``engine.rows_prefill``.  Each request keeps its own times
+``engine.rows_prefill`` and ``engine.cache_donations``, the steps that
+consumed the cache they were given and so wrote its new positions in
+place (read on the host from one leaf's ``is_deleted()``; the step
+donates the cache, so the count equals ``engine.steps`` wherever the
+backend can donate).  Each request keeps its own times
 (``t_submit``, ``t_admit``, ``t_first``; perf_counter) under its ``rid``.
 For a model with MoE layers the step also counts, on the device, the
 tokens of all rows routed to each held expert of each MoE layer; the
@@ -90,8 +94,11 @@ class Engine:
         self.slots: list[Optional[Request]] = [None] * batch_slots
         self.cache = lm.init_cache(spec, rt, batch_slots, kv_len)
         self.routed = spec.moe is not None
+        # the step consumes the cache it is given and writes the new
+        # positions into it in place (``lm.decode_step``)
         self.step_fn = jax.jit(make_serve_step(spec, rt, rules,
-                                               routed=self.routed))
+                                               routed=self.routed),
+                               donate_argnums=(1,))
         self.queue: list[Request] = []
         self.n_steps = 0
         self._sampled: Optional[float] = None   # end of the last sample
@@ -151,9 +158,11 @@ class Engine:
                 st.set(rows=live)
                 t_dispatch = time.perf_counter()
                 with span("engine.dispatch"):
+                    given = jax.tree.leaves(self.cache)[0]
                     out = self.step_fn(self.params, self.cache,
                                        jnp.asarray(tok_host))
                     logits, self.cache = out[0], out[1]
+                donated = given.is_deleted()
                 with span("engine.sample"):
                     nxt = jnp.argmax(logits[:, 0], axis=-1)
                     if self.routed:
@@ -186,6 +195,7 @@ class Engine:
             self._sampled, self._admit_s = t_sampled, 0.0
             self.n_steps += 1
             metrics.counter("engine.steps").inc()
+            metrics.counter("engine.cache_donations").inc(int(donated))
             metrics.counter("engine.rows_prefill").inc(prefill)
             metrics.counter("engine.tokens_out").inc(useful)
             metrics.histogram("engine.useful_rows").observe(useful)

@@ -7,9 +7,13 @@ import pytest
 
 from repro.configs import ARCHS, get
 from repro.models import (RuntimeCfg, decode_step, init_cache, init_params,
-                          loss_fn)
+                          lm, loss_fn)
+
+from plain_decode import engine_matches_plain
 
 RT = RuntimeCfg(attention_impl="chunked", attn_chunk=64)
+RT_F32 = RuntimeCfg(attention_impl="chunked", attn_chunk=64,
+                    param_dtype="float32", compute_dtype="float32")
 
 
 def _batch(spec, B=2, S=32):
@@ -56,6 +60,42 @@ def test_smoke_decode_step(name):
     assert bool(jnp.isfinite(logits).all()), name
     # cache structure preserved
     assert jax.tree.structure(cache) == jax.tree.structure(cache2)
+
+
+@pytest.mark.parametrize("name", ARCHS)
+def test_engine_decode_matches_plain_decode(name):
+    """Decode steps through the Engine, which donates its cache, carries
+    full-attention caches (GQA, MLA) through the layer scan and writes
+    each layer's new position into the stack in place, and passes ring,
+    mamba, rwkv and cross-attention caches through the scan as before,
+    give each step's logits and the final caches of a plain decode that
+    runs the layers one by one on caches of their own."""
+    spec = get(name).smoke
+    params = init_params(spec, RT_F32, jax.random.PRNGKey(0))
+    _, _, steps = engine_matches_plain(
+        spec, RT_F32, params, [[3, 1, 4], [1, 5, 9, 2, 6]], max_new=3,
+        kv_len=64, rel=1e-5)
+    assert steps == 7
+
+
+def test_decode_matches_forward_on_a_periodic_stack():
+    """gemma2 alternates local and global layers (a period of 2): prefill
+    by decode, one token a step through the cache, gives the full
+    forward's logits, so the decode runs the layers in their order."""
+    spec = get("gemma2-27b").smoke
+    assert lm.layer_pattern(spec)[1] == 2
+    params = init_params(spec, RT_F32, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
+                                spec.vocab)
+    want = lm.forward(params, tokens, spec, RT_F32)
+    step = jax.jit(lambda p, c, t: decode_step(p, c, t, spec, RT_F32))
+    cache, got = init_cache(spec, RT_F32, 2, 32), []
+    for t in range(tokens.shape[1]):
+        logits, cache = step(params, cache, tokens[:, t:t + 1])
+        got.append(logits)
+    got = jnp.concatenate(got, axis=1)
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
 
 
 def test_full_configs_match_assignment():

@@ -12,7 +12,9 @@ process may load the TPU library, and a module that touched it at
 collection would break the other test workers.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +74,29 @@ def _hbm_bytes(compiled) -> int:
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
+def _bytes(tree) -> int:
+    return sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(tree))
+
+
+def _entry_copies(compiled) -> list:
+    """The shapes (dims in order) of the copies in the entry computation,
+    outside every loop: relayouts of whole arguments land there."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return [tuple(int(d) for d in m.split(","))
+            for line in entry.splitlines()
+            if re.search(r" copy(-start)?\(", line)
+            for m in re.findall(r"\[([\d,]+)\]", line.split("=", 1)[1]
+                                .split(" copy")[0])]
+
+
+def _serve_step(spec, rt, **kw):
+    """The serve step jitted as ``Engine`` jits it: the cache donated."""
+    return jax.jit(make_serve_step(spec, rt, **kw), donate_argnums=(1,))
+
+
 def test_cost_reduce_compiles(one_chip):
     # a sweep-sized busy-group contraction: [B, K] x [G, K]
     x = jax.ShapeDtypeStruct((2048, 512), jnp.float32, sharding=one_chip)
@@ -112,8 +137,9 @@ def test_granite_34b_serve_step_fits_one_chip(one_chip):
     cache = _on(one_chip, jax.eval_shape(
         lambda: lm.init_cache(spec, rt, 8, 4096)))
     tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_serve_step(spec, rt)).lower(
-        params, cache, tok).compile()
+    compiled = _serve_step(spec, rt).lower(params, cache, tok).compile()
+    # the k/v stack is updated in place: all of the cache is aliased
+    assert compiled.memory_analysis().alias_size_in_bytes >= _bytes(cache)
     assert _hbm_bytes(compiled) <= HBM
 
 
@@ -121,7 +147,10 @@ def test_deepseek_v2_serve_step_fits_one_chip(one_chip):
     """The benchmark's cut: the dense layer and 4 MoE layers at published
     widths, each holding 20 of the 160 experts; 128 slots x 1536 latent
     positions.  Attention reads the latent cache and forms no per-head
-    key or value of it; the held experts go through the ragged dot."""
+    key or value of it; the held experts go through the ragged dot.  The
+    step takes the donated cache and writes each layer's new position
+    into it in place: all of it is aliased, and no copy of the stacked
+    latent cache is made around the layer scan."""
     spec = cut_depth(get("deepseek-v2-236b").spec, 5)
     spec = dataclasses.replace(spec, moe=dataclasses.replace(spec.moe,
                                                              n_held=20))
@@ -131,11 +160,17 @@ def test_deepseek_v2_serve_step_fits_one_chip(one_chip):
     cache = _on(one_chip, jax.eval_shape(
         lambda: lm.init_cache(spec, rt, 128, 1536)))
     tok = jax.ShapeDtypeStruct((128, 1), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_serve_step(spec, rt, routed=True)).lower(
+    compiled = _serve_step(spec, rt, routed=True).lower(
         params, cache, tok).compile()
     text = compiled.as_text().replace(" ", "")
-    assert not [d for d in (128, 192, 256) if f"[128,1536,128,{d}]" in text]
+    # per head, in either order of the rows and the (time-major) positions
+    assert not [d for d in (128, 192, 256)
+                for shape in (f"[128,1536,128,{d}]", f"[1536,128,128,{d}]")
+                if shape in text]
     assert "ragged-dot" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= _bytes(cache)
+    stacked = sorted(cache["slots"][0]["attn"]["ckv"].shape)
+    assert not [c for c in _entry_copies(compiled) if sorted(c) == stacked]
     assert _hbm_bytes(compiled) <= HBM
 
 

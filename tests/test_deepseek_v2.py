@@ -8,6 +8,9 @@ size on seeded random weights (CPU).
 - Group-limited routing on a hand example; yarn RoPE's ramp and scale.
 - Planted faults (gates renormalized; no group limit) fail the first
   comparison, and the decode step forms no per-head copy of the cache.
+- The engine's donated decode, which writes each layer's new position
+  into the time-major latent cache in place, gives the logits and the
+  cache of a plain per-layer decode (``plain_decode.py``).
 """
 import dataclasses
 import importlib
@@ -28,6 +31,8 @@ from repro.core import MLASpec, ModelSpec, MoESpec  # noqa: E402
 from repro.models import RuntimeCfg, init_params, layers as L, lm  # noqa: E402
 from repro.models.common import Param  # noqa: E402
 from repro.serve import Engine, Request  # noqa: E402
+
+from plain_decode import engine_matches_plain  # noqa: E402
 
 ref = importlib.import_module("deepseek_v2")
 weights = importlib.import_module("weights")
@@ -220,4 +225,29 @@ def test_decode_forms_no_per_head_cache():
     per_head = {(B, T, N, d) for d in (m.nope_dim, m.v_dim,
                                        m.nope_dim + m.rope_dim)}
     assert not shapes & per_head, shapes & per_head
-    assert (B, N, 1, T) in shapes                    # latent scores
+    assert (B, 1, T, N) in shapes                    # latent scores
+
+
+def test_engine_decode_matches_plain_decode():
+    """Through the Engine the latent cache is donated and each layer
+    writes its new position into it in place, the scanned layers into
+    the stack they carry.  Each step's logits and the final cache equal
+    those of a plain decode that runs the layers one by one, and read
+    by position through the [B, T, R] view of the time-major cache,
+    every row holds exactly the positions the steps wrote."""
+    params, _ = _weights(SPEC)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, SPEC.vocab, size=n) for n in (4, 7, 2)]
+    got, _, steps = engine_matches_plain(SPEC, F32, params, prompts,
+                                         max_new=5, kv_len=24, rel=1e-5)
+    assert steps == 11
+    m = SPEC.mla
+    for c, layers in ((got["prefix"][0]["attn"], 1),
+                      (got["slots"][0]["attn"], SPEC.n_layers - 1)):
+        ckv, kr = (np.asarray(c[k], np.float32).reshape(
+            layers, 24, 3, -1).swapaxes(1, 2) for k in ("ckv", "kr"))
+        assert ckv.shape[-1] == m.kv_lora and kr.shape[-1] == m.rope_dim
+        for a in (ckv, kr):                       # [layers, B, T, R]
+            assert (np.abs(a[:, :, :steps]).max(-1) > 0).all()
+            assert not a[:, :, steps:].any()
+        assert (np.asarray(c["pos"]) == steps).all()

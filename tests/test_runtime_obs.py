@@ -188,6 +188,7 @@ def test_engine_instruments_two_slots():
     assert c("engine.requests_admitted").value == 3
     assert c("engine.prompt_tokens_admitted").value == 9
     assert c("engine.rows_prefill").value == 9
+    assert c("engine.cache_donations").value == steps   # the CPU donates
     assert all(r.t_submit <= r.t_admit <= r.t_first for r in reqs)
     # each step's phases are its children; admissions carry their rids
     step_ids = {e.id for e in prof.events if e.name == "engine.step"}
@@ -198,6 +199,23 @@ def test_engine_instruments_two_slots():
     admits = [e for e in prof.events if e.name == "engine.admit"]
     assert sorted(i for a in admits for i in a.args["rids"]) == [0, 1, 2]
     assert [a.args["prompt_tokens"] for a in admits] == [6, 3]
+
+
+def test_engine_counts_only_consumed_caches():
+    """``engine.cache_donations`` counts the steps that consumed the
+    cache they were given: none where the step does not donate."""
+    from repro.serve import Engine, Request, make_serve_step
+    spec = ModelSpec(name="m", n_layers=2, d_model=64, n_heads=4,
+                     n_kv_heads=2, d_ff=128, vocab=256)
+    rt = RuntimeCfg(attention_impl="naive")
+    eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
+                 batch_slots=2, kv_len=16)
+    eng.step_fn = jax.jit(make_serve_step(spec, rt))
+    eng.submit(Request(rid=0, prompt=np.array([1, 2]), max_new=2))
+    eng.run(max_steps=8)
+    c = metrics.counter
+    assert c("engine.steps").value == eng.n_steps == 3
+    assert c("engine.cache_donations").value == 0
 
 
 def test_engine_admit_makes_no_device_transfer():
