@@ -39,8 +39,10 @@ SHAPES = {
     "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
 }
 
-# long_500k needs sub-quadratic sequence handling (see DESIGN.md
-# §Shape-applicability): run only for SSM / hybrid / sliding-window archs.
+# long_500k needs sub-quadratic sequence handling: a full-attention
+# decoder would hold and read every one of the 524,288 positions in each
+# layer's KV cache.  Run it only for the SSM / hybrid / sliding-window
+# archs, whose recurrent states and windows bound most layers' caches.
 LONG_OK = {"rwkv6-7b", "jamba-v0.1-52b", "gemma2-27b"}
 
 

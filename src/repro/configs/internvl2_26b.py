@@ -3,7 +3,9 @@ vocab=92553 — InternViT frontend STUB (precomputed patch embeddings,
 256 vision tokens) + InternLM2 backbone [arXiv:2404.16821].
 
 vocab=92553 is not 16-divisible: the embedding/LM-head stay replicated
-over the model axis (data/FSDP-sharded instead) — noted in DESIGN.md."""
+over the model axis (data/FSDP-sharded instead), since
+``parallel.sharding.param_pspec`` skips a dimension the axis does not
+divide."""
 from repro.core import ModelSpec
 from repro.models.common import RuntimeCfg
 

@@ -140,8 +140,7 @@ def lower_cell(arch: Arch, shape_name: str, *, multi_pod: bool = False,
                                           zero1=rt.zero1,
                                           data_axes=data_axes_of(mesh))
             bsds, bshard = batch_specs(arch, shape, mesh)
-            step = make_train_step(spec, rt, OptCfg(), rules,
-                                   grad_accum=rt.grad_accum)
+            step = make_train_step(spec, rt, OptCfg(), rules)
             fn = jax.jit(step,
                          in_shardings=(p_shard, o_shard, bshard),
                          donate_argnums=(0, 1) if donate else ())

@@ -25,18 +25,15 @@ class RuntimeCfg:
     """Runtime knobs orthogonal to the architecture itself."""
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
-    attention_impl: str = "chunked"     # naive | chunked | pallas
-    attn_chunk: int = 1024              # kv-chunk for online-softmax attention
-    attn_q_block: bool = True           # block queries via lax.map (see §Perf
-                                        # p1: GSPMD-hostile for sharded seq)
-    remat: str = "none"                 # none | full | dots
-    scan_layers: bool = True
+    attention_impl: str = "chunked"     # chunked: online softmax under a
+                                        # checkpoint; naive: one masked
+                                        # softmax; pallas: the Pallas kernel
+    attn_chunk: int = 1024              # q block and kv chunk of "chunked"
+    remat: str = "none"                 # none | full (checkpoint each layer)
     sp: bool = True                     # sequence-parallel activation layout
     zero1: bool = True                  # shard optimizer state over data axes
-    grad_accum: int = 1
-    loss_chunk: int = 0                 # >0: CE loss scanned over seq chunks
-    moe_capacity: float = 1.25          # expert capacity factor
-    logical_rules: tuple = ()           # overrides for logical->mesh mapping
+    moe_capacity: float = 1.25          # expert capacity factor of the EP
+                                        # shard_map path (one chip: dropless)
 
 
 def dt(name: str):

@@ -148,16 +148,15 @@ def attn_naive(q, k, v, *, causal: bool, window: Optional[int],
 
 def attn_chunked(q, k, v, *, causal: bool, window: Optional[int],
                  softcap: Optional[float], chunk: int = 1024,
-                 q_offset=0, q_block: bool = True,
-                 scale: Optional[float] = None) -> jax.Array:
-    """Online-softmax (flash) attention: q blocked via lax.map, kv scanned.
+                 q_offset=0, scale: Optional[float] = None) -> jax.Array:
+    """Online-softmax (flash) attention: q blocked via lax.map where the
+    chunk divides the queries, kv scanned.
 
-    Live memory O(q_block·chunk) per step instead of O(Sq·Sk) — this is
-    the jnp rendering of the Pallas kernel in
-    ``repro.kernels.flash_attention``."""
+    Live memory O(chunk²) per step instead of O(Sq·Sk) — this is the jnp
+    rendering of the Pallas kernel in ``repro.kernels.flash_attention``."""
     b, sq, n, g, d = q.shape
     qb = chunk
-    if q_block and sq > qb and sq % qb == 0:
+    if sq > qb and sq % qb == 0:
         nb = sq // qb
         qblocks = q.reshape(b, nb, qb, n, g, d).transpose(1, 0, 2, 3, 4, 5)
         offs = q_offset + jnp.arange(nb) * qb
@@ -243,8 +242,7 @@ def attn_core(q, k, v, rt: RuntimeCfg, *, causal: bool, window=None,
         fn = jax.checkpoint(
             functools.partial(attn_chunked, causal=causal, window=window,
                               softcap=softcap, chunk=rt.attn_chunk,
-                              q_offset=q_offset,
-                              q_block=rt.attn_q_block, scale=scale),
+                              q_offset=q_offset, scale=scale),
             prevent_cse=False)
         return fn(q, k, v)
     return attn_naive(q, k, v, causal=causal, window=window,
@@ -252,31 +250,47 @@ def attn_core(q, k, v, rt: RuntimeCfg, *, causal: bool, window=None,
 
 
 # ---------------------------------------------------------------------------
-# Decode caches written by position
+# Decode caches
 # ---------------------------------------------------------------------------
+# Each layer kind builds its cache beside its params (``init_*_cache``) and
+# is the only code that reads or writes it.  A prefix layer gets its own
+# cache; a layer of a scanned slot gets the stack of the slot's layers (a
+# leading layer axis) and its index ``layer`` in it.  The next position to
+# write is one scalar for the whole model, handed to each layer by
+# ``lm.decode_step``.
 
-def cache_pos(cache: dict, layer=None) -> jax.Array:
-    """The next position to write in ``cache`` (of layer ``layer`` of a
-    stacked cache)."""
-    return cache["pos"] if layer is None else cache["pos"][layer]
+def layer_of(cache: dict, layer=None) -> dict:
+    """Layer ``layer``'s arrays of a stacked cache (``cache`` itself when
+    ``layer`` is None)."""
+    if layer is None:
+        return cache
+    return {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for k, a in cache.items()}
+
+
+def put_layer(cache: dict, new: dict, layer=None) -> dict:
+    """``cache`` with layer ``layer``'s arrays replaced whole by ``new``
+    (``new`` itself when ``layer`` is None)."""
+    if layer is None:
+        return new
+    return {k: jax.lax.dynamic_update_index_in_dim(a, new[k], layer, 0)
+            for k, a in cache.items()}
 
 
 def write_cache(cache: dict, new: dict, pos, axis: int,
                 layer=None) -> tuple[dict, dict]:
     """Write each ``new[k]`` into ``cache[k]`` from position ``pos`` along
-    ``axis`` of one layer's array, and advance the position.  Returns (the
-    cache to hand on, this layer's arrays after the write).
+    ``axis`` of one layer's array.  Returns (the written cache, this
+    layer's arrays after the write).
 
-    With ``layer``, ``cache`` is the stack of a scanned slot's layers (a
-    leading layer axis, one ``pos`` per layer), carried through the scan:
-    each write is one ``dynamic_update_slice`` at (``layer``, ``pos``),
-    which XLA makes in place in a donated or carried buffer, and the layer
-    is read back from the updated stack."""
-    n = next(iter(new.values())).shape[axis]
+    With ``layer`` each write is one ``dynamic_update_slice`` at
+    (``layer``, ``pos``) of the stack, which XLA makes in place in a
+    donated or carried buffer, and the layer is read back from the
+    updated stack."""
     if layer is None:
         out = {k: jax.lax.dynamic_update_slice_in_dim(cache[k], v, pos, axis)
                for k, v in new.items()}
-        return {**out, "pos": pos + n}, out
+        return out, out
     out = {}
     for k, v in new.items():
         start = [0] * cache[k].ndim
@@ -284,17 +298,14 @@ def write_cache(cache: dict, new: dict, pos, axis: int,
         out[k] = jax.lax.dynamic_update_slice(cache[k], v[None], start)
     # one copy of the layer for all of its products: XLA would otherwise
     # slice it out of the stack once for each
-    mine = jax.lax.optimization_barrier(
-        {k: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-         for k, a in out.items()})
-    return {**out, "pos": cache["pos"].at[layer].add(n)}, mine
+    return out, jax.lax.optimization_barrier(layer_of(out, layer))
 
 
 # ---------------------------------------------------------------------------
 # GQA attention layer (granite/gemma2/qwen3/minitron/whisper/internvl/jamba)
 # ---------------------------------------------------------------------------
 
-def init_gqa(ini: Initializer, spec, prefix: str = "", cross: bool = False) -> dict:
+def init_gqa(ini: Initializer, spec, prefix: str = "") -> dict:
     H, DHd = spec.d_model, spec.head_dim
     nkv = max(1, spec.n_kv_heads)
     g = max(1, spec.n_heads // nkv)
@@ -312,16 +323,31 @@ def init_gqa(ini: Initializer, spec, prefix: str = "", cross: bool = False) -> d
     return p
 
 
+def init_gqa_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int,
+                   window: Optional[int] = None) -> dict:
+    """``k`` and ``v`` [B,T,NKV,DH] of ``kv_len`` positions, or of the
+    ``window`` last ones where that is shorter (a ring, rewritten whole
+    each step).  Also the cross-attention cache, of the encoder's
+    positions."""
+    t = min(kv_len, window) if window else kv_len
+    shape = (batch, t, max(1, spec.n_kv_heads), spec.head_dim)
+    return {"k": jnp.zeros(shape, dt(rt.compute_dtype)),
+            "v": jnp.zeros(shape, dt(rt.compute_dtype))}
+
+
 @scoped
 def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
                   window: Optional[int] = None, causal: bool = True,
                   cross_kv: Optional[jax.Array] = None,
-                  cache: Optional[dict] = None,
+                  cache: Optional[dict] = None, pos=None,
                   layer=None) -> tuple[jax.Array, Optional[dict]]:
-    """Grouped-query attention.  The self-attention cache holds ``k``
-    and ``v`` [B,T,NKV,DH]; with ``layer`` it is the stack of a scanned
-    slot's layers and this is layer ``layer`` of it (``write_cache``)."""
+    """Grouped-query attention.  Decoding at ``pos`` (the position of x's
+    first token), it writes x's keys and values into its self-attention
+    ``cache`` (``init_gqa_cache``) and attends over it; a ``cache`` with
+    no ``pos`` holds the cross-attention keys and values, read only.
+    With ``layer`` the cache is the stack of a scanned slot's layers and
+    this is layer ``layer`` of it.  Returns (x, the new cache or None)."""
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
     q = jnp.einsum("bsh,hngd->bsngd", h, cast(p["w_q"].value, rt))
@@ -329,23 +355,24 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         q = rms_norm(p["qn"], q)
     q = constrain(q, rules, (BATCH, SEQ, KV, QGRP, HDIM))
 
-    if cache is not None and "pos" in cache:   # self-attn decode
+    new_cache = None
+    if pos is not None:                          # self-attn decode
         k_new = jnp.einsum("bsh,hnd->bsnd", h, cast(p["w_k"].value, rt))
         v_new = jnp.einsum("bsh,hnd->bsnd", h, cast(p["w_v"].value, rt))
         if p.get("kn") is not None:
             k_new = rms_norm(p["kn"], k_new)
-        pos = cache_pos(cache, layer)
         if positions is None:
             positions = pos + jnp.zeros(x.shape[:2], jnp.int32)
         k_new = rope(k_new, positions)
         q = rope(q, positions)
-        klen = cache["k"].shape[1]
+        klen = cache["k"].shape[-3]
         s_new = x.shape[1]
         if window is not None and klen <= window:
             # ring(-ish) cache for sliding-window layers: shift + append
-            k = jnp.concatenate([cache["k"][:, s_new:], k_new], axis=1)
-            v = jnp.concatenate([cache["v"][:, s_new:], v_new], axis=1)
-            new_cache = {"k": k, "v": v, "pos": pos + s_new}
+            old = layer_of(cache, layer)
+            k = jnp.concatenate([old["k"][:, s_new:], k_new], axis=1)
+            v = jnp.concatenate([old["v"][:, s_new:], v_new], axis=1)
+            new_cache = put_layer(cache, {"k": k, "v": v}, layer)
             filled = jnp.minimum(pos + s_new, klen)
             valid = jnp.arange(klen) >= (klen - filled)
             scale = 1.0 / math.sqrt(q.shape[-1])
@@ -361,9 +388,9 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
             out5 = attn_core(q, kv["k"], kv["v"], rt, causal=True, window=window,
                              softcap=spec.attn_softcap, q_offset=pos)
     elif cache is not None:                      # cached cross-attn (k/v only)
-        k, v = cache["k"], cache["v"]
+        kv = layer_of(cache, layer)
         new_cache = cache
-        out5 = attn_core(q, k, v, rt, causal=False, window=None,
+        out5 = attn_core(q, kv["k"], kv["v"], rt, causal=False, window=None,
                          softcap=spec.attn_softcap)
     else:
         src = cross_kv if cross_kv is not None else h
@@ -375,7 +402,6 @@ def gqa_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
             if positions is None:
                 positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
             q, k = rope(q, positions), rope(k, positions)
-        new_cache = {"k": k, "v": v} if cross_kv is not None else None
         out5 = attn_core(q, k, v, rt, causal=causal and cross_kv is None,
                          window=window, softcap=spec.attn_softcap)
     out = jnp.einsum("bsngd,ngdh->bsh", out5, cast(p["w_o"].value, rt))
@@ -405,17 +431,26 @@ def init_mla(ini: Initializer, spec, prefix: str = "") -> dict:
     }
 
 
+def init_mla_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
+    """The normed latent ``ckv`` [T,B,kv_lora] and the rotated ``kr``
+    [T,B,rope_dim], time major: the layout ``_mla_latent``'s products
+    read."""
+    m, cdt = spec.mla, dt(rt.compute_dtype)
+    return {"ckv": jnp.zeros((kv_len, batch, m.kv_lora), cdt),
+            "kr": jnp.zeros((kv_len, batch, m.rope_dim), cdt)}
+
+
 @scoped
 def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                   rules: Optional[AxisRules], *, positions=None,
-                  cache: Optional[dict] = None,
+                  cache: Optional[dict] = None, pos=None,
                   layer=None) -> tuple[jax.Array, Optional[dict]]:
-    """Multi-head latent attention.  The cache holds the normed latent
-    ``ckv`` [T,B,kv_lora] and the rotated ``kr`` [T,B,rope_dim], time
-    major, the layout the latent products read; with ``layer`` it is the
-    stack of a scanned slot's layers (``write_cache``).  With a cache,
-    attention runs in the latent space (``mla_decode``); without one
-    (prefill, training) keys and values are expanded per head."""
+    """Multi-head latent attention.  Decoding at ``pos`` (the position of
+    x's first token), it writes x's latents into ``cache``
+    (``init_mla_cache``; with ``layer`` the stack of a scanned slot's
+    layers) and attends in the latent space (``mla_decode``); without a
+    position (prefill, training) keys and values are expanded per
+    head."""
     m = spec.mla
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
@@ -425,14 +460,14 @@ def mla_attention(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
 
     ckv_new = rms_norm(p["ln_kv"], jnp.einsum("bsh,hr->bsr", h, cast(p["w_dkv"].value, rt)))
     kr_new = jnp.einsum("bsh,hd->bsd", h, cast(p["w_kr"].value, rt))
-    pos = cache_pos(cache, layer) if cache is not None else 0
     if positions is None:
-        positions = pos + jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        positions = (0 if pos is None else pos) + jnp.broadcast_to(
+            jnp.arange(x.shape[1]), x.shape[:2])
     inv_freq = mla_rope(m)
     qr = rope(qr, positions, inv_freq=inv_freq)
     kr_new = rope(kr_new[:, :, None], positions, inv_freq=inv_freq)[:, :, 0]
     scale = mla_softmax_scale(m)
-    if cache is not None:
+    if pos is not None:
         new_cache, lat = write_cache(
             cache, {"ckv": ckv_new.transpose(1, 0, 2),
                     "kr": kr_new.transpose(1, 0, 2)}, pos, 0, layer)
@@ -666,21 +701,21 @@ def _route_and_compute(h, wr, wg, wu, wd, *, mo, capacity_factor: float,
 
 @scoped
 def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
-            rules: Optional[AxisRules], *, capacity_factor: float = 0.0,
-            with_counts: bool = False, layer=None):
+            rules: Optional[AxisRules], *, with_counts: bool = False,
+            layer=None):
     """Routed experts (``route``) plus the shared experts once.
 
     On one device the held experts' product is dropless
     (``_held_experts``).  With a mesh attached to ``rules`` the block
     runs under ``shard_map``: tokens stay local to their data shard,
     the held experts are sharded over the expert (model) axis with a
-    static capacity per expert, and dispatch/combine are explicit
-    AllToAlls — the production EP pattern (and the one the STG matcher
-    emits).  ``with_counts`` also returns the tokens routed to each held
-    expert [held] (int32).  ``layer``: the expert weights in ``p`` are
-    the stack of all MoE layers and this is layer ``layer`` of it."""
+    static capacity per expert (``rt.moe_capacity``), and
+    dispatch/combine are explicit AllToAlls — the production EP pattern
+    (and the one the STG matcher emits).  ``with_counts`` also returns
+    the tokens routed to each held expert [held] (int32).  ``layer``: the
+    expert weights in ``p`` are the stack of all MoE layers and this is
+    layer ``layer`` of it."""
     mo = spec.moe
-    capacity_factor = capacity_factor or rt.moe_capacity
     b, s, H = x.shape
     h = rms_norm(p["ln"], x)
     h = constrain(h, rules, (BATCH, SEQ, EMB))
@@ -719,7 +754,7 @@ def moe_ffn(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
         with jax.named_scope("moe_experts"):
             fn = shard_map(
                 functools.partial(_route_and_compute, mo=mo,
-                                  capacity_factor=capacity_factor,
+                                  capacity_factor=rt.moe_capacity,
                                   a2a_axis=ep_axis, gather_axes=gather,
                                   count_axes=count_axes),
                 mesh=mesh,
@@ -767,6 +802,14 @@ def init_mamba(ini: Initializer, spec, prefix: str = "") -> dict:
     }
 
 
+def init_mamba_cache(spec, rt: RuntimeCfg, batch: int) -> dict:
+    """The last 3 conv inputs [B,3,Din] and the SSM state [B,Din,P],
+    rewritten whole each step."""
+    din = spec.ssm.expand * spec.d_model
+    return {"conv": jnp.zeros((batch, 3, din), dt(rt.compute_dtype)),
+            "ssm": jnp.zeros((batch, din, spec.ssm.d_state), jnp.float32)}
+
+
 def _ssm_scan(dA: jax.Array, dBx: jax.Array, h0: jax.Array,
               chunk: int) -> tuple[jax.Array, jax.Array]:
     """h_t = dA_t * h_{t-1} + dBx_t over axis 1; returns (all h, last h).
@@ -798,8 +841,11 @@ def _ssm_scan(dA: jax.Array, dBx: jax.Array, h0: jax.Array,
 
 @scoped
 def mamba_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
-                rules: Optional[AxisRules], *,
-                cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+                rules: Optional[AxisRules], *, cache: Optional[dict] = None,
+                layer=None) -> tuple[jax.Array, Optional[dict]]:
+    """Selective SSM.  Decoding, it continues from ``cache``
+    (``init_mamba_cache``; with ``layer`` the stack of a scanned slot's
+    layers) and returns it with this layer's state after x."""
     ss = spec.ssm
     b, s, H = x.shape
     din = ss.expand * H
@@ -810,8 +856,9 @@ def mamba_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     xs, z = xz[..., :din], xz[..., din:]
 
     conv_w = cast(p["conv"].value, rt)
-    if cache is not None:
-        prev = cache["conv"]                       # [B, 3, Din]
+    mine = None if cache is None else layer_of(cache, layer)
+    if mine is not None:
+        prev = mine["conv"]                        # [B, 3, Din]
         xpad = jnp.concatenate([prev, xs], axis=1)
         new_conv = xpad[:, -3:]
     else:
@@ -828,14 +875,15 @@ def mamba_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
     A = -jnp.exp(p["A_log"].value)                  # [Din, P]
     dA = jnp.exp(dtt[..., None] * A[None, None])    # [B,S,Din,P]
     dBx = (dtt * xc.astype(jnp.float32))[..., None] * Bt[:, :, None, :].astype(jnp.float32)
-    h0 = cache["ssm"] if cache is not None else jnp.zeros((b, din, ss.d_state),
-                                                          jnp.float32)
+    h0 = mine["ssm"] if mine is not None else jnp.zeros((b, din, ss.d_state),
+                                                        jnp.float32)
     hs, h_last = _ssm_scan(dA, dBx, h0, chunk=min(s, 256))
     y = jnp.einsum("bsip,bsp->bsi", hs, Ct.astype(jnp.float32)).astype(x.dtype)
     y = y + xc * cast(p["D"].value, rt)
     y = y * jax.nn.silu(z)
     out = jnp.einsum("bsi,ih->bsh", y, cast(p["w_out"].value, rt))
-    new_cache = {"conv": new_conv, "ssm": h_last} if cache is not None else None
+    new_cache = None if cache is None else put_layer(
+        cache, {"conv": new_conv, "ssm": h_last}, layer)
     return x + constrain(out, rules, (BATCH, SEQ, EMB)), new_cache
 
 
@@ -867,6 +915,15 @@ def init_rwkv6(ini: Initializer, spec, prefix: str = "") -> dict:
                     scale=1.0 / np.sqrt(spec.d_ff))
     p["w_cr"] = ini(prefix + "w_cr", (H, H), (EMB, EMB))
     return p
+
+
+def init_rwkv6_cache(spec, rt: RuntimeCfg, batch: int) -> dict:
+    """The WKV state [B,N,D,D] and the last normed inputs of the time and
+    channel mixes [B,H], rewritten whole each step."""
+    nh, dh, cdt = spec.n_heads, spec.head_dim, dt(rt.compute_dtype)
+    return {"wkv": jnp.zeros((batch, nh, dh, dh), jnp.float32),
+            "shift_tm": jnp.zeros((batch, spec.d_model), cdt),
+            "shift_cm": jnp.zeros((batch, spec.d_model), cdt)}
 
 
 def _token_shift(x: jax.Array, prev: Optional[jax.Array]) -> jax.Array:
@@ -981,12 +1038,16 @@ def rwkv6_channel_mix(p: dict, x: jax.Array, rt: RuntimeCfg,
 
 def rwkv6_layer(p: dict, x: jax.Array, spec, rt: RuntimeCfg,
                 rules: Optional[AxisRules], *, chunk: int = 32,
-                cache: Optional[dict] = None) -> tuple[jax.Array, Optional[dict]]:
+                cache: Optional[dict] = None,
+                layer=None) -> tuple[jax.Array, Optional[dict]]:
+    """RWKV-6 time and channel mix.  Decoding, it continues from
+    ``cache`` (``init_rwkv6_cache``; with ``layer`` the stack of a scanned
+    slot's layers) and returns it with this layer's state after x."""
+    mine = None if cache is None else layer_of(cache, layer)
     x, state_last, h = rwkv6_time_mix(p, x, spec, rt, rules, chunk=chunk,
-                                      cache=cache)
-    x, hc = rwkv6_channel_mix(p, x, rt, rules, cache=cache)
-    new_cache = None
-    if cache is not None:
-        new_cache = {"wkv": state_last, "shift_tm": h[:, -1],
-                     "shift_cm": hc[:, -1]}
-    return x, new_cache
+                                      cache=mine)
+    x, hc = rwkv6_channel_mix(p, x, rt, rules, cache=mine)
+    if cache is None:
+        return x, None
+    return x, put_layer(cache, {"wkv": state_last, "shift_tm": h[:, -1],
+                                "shift_cm": hc[:, -1]}, layer)

@@ -147,57 +147,44 @@ def _index(tree, i):
 
 
 def _apply_slot(p: dict, x, spec, rt, rules, kind: dict, *,
-                positions=None, cache=None, cross_kv=None, cross_p=None,
-                cross_cache=None, moe_layer=None, layer=None):
+                positions=None, cache=None, pos=None, cross_kv=None,
+                cross_p=None, layer=None):
     """One layer: (x, its new cache or None, the tokens routed to each
-    held expert [held] for an MoE layer, else None).  ``moe_layer``: the
-    expert weights in ``p`` are the stack of the slot's layers and this
-    is layer ``moe_layer`` of it (``layers.moe_ffn``); ``layer``: so is
-    the attention cache (``layers.write_cache``)."""
+    held expert [held] for an MoE layer, else None).  Decoding, ``cache``
+    is the layer's cache and ``pos`` the position of x's first token.
+    With ``layer`` the cache, and an MoE layer's expert weights in ``p``,
+    are the stacks of the slot's layers and this is layer ``layer`` of
+    them (``layers.write_cache``, ``layers.moe_ffn``)."""
+    c = cache or {}
     new_cache: dict = {}
     routed = None
     if kind["mixer"] == "attn":
-        if spec.block == "mla":
-            x, c = L.mla_attention(p["attn"], x, spec, rt, rules,
-                                   positions=positions, layer=layer,
-                                   cache=None if cache is None else cache.get("attn"))
-        else:
-            x, c = L.gqa_attention(p["attn"], x, spec, rt, rules,
-                                   positions=positions, window=kind["window"],
-                                   layer=layer,
-                                   cache=None if cache is None else cache.get("attn"))
-        if c is not None:
-            new_cache["attn"] = c
+        attn = L.mla_attention if spec.block == "mla" else functools.partial(
+            L.gqa_attention, window=kind["window"])
+        x, new_cache["attn"] = attn(p["attn"], x, spec, rt, rules,
+                                    positions=positions, cache=c.get("attn"),
+                                    pos=pos, layer=layer)
     elif kind["mixer"] == "mamba":
-        x, c = L.mamba_layer(p["mamba"], x, spec, rt, rules,
-                             cache=None if cache is None else cache.get("mamba"))
-        if c is not None:
-            new_cache["mamba"] = c
+        x, new_cache["mamba"] = L.mamba_layer(p["mamba"], x, spec, rt, rules,
+                                              cache=c.get("mamba"), layer=layer)
     else:
-        x, c = L.rwkv6_layer(p["rwkv"], x, spec, rt, rules,
-                             cache=None if cache is None else cache.get("rwkv"))
-        if c is not None:
-            new_cache["rwkv"] = c
+        x, new_cache["rwkv"] = L.rwkv6_layer(p["rwkv"], x, spec, rt, rules,
+                                             cache=c.get("rwkv"), layer=layer)
     if cross_p is not None:
-        x, cc = L.gqa_attention(cross_p, x, spec, rt, rules,
-                                cross_kv=cross_kv, cache=cross_cache)
-        if cache is not None and cc is not None:
-            new_cache["cross"] = cc
+        x, new_cache["cross"] = L.gqa_attention(
+            cross_p, x, spec, rt, rules, cross_kv=cross_kv,
+            cache=c.get("cross"), layer=layer)
     if kind["ffn"] == "moe":
         x, routed = L.moe_ffn(p["moe"], x, spec, rt, rules, with_counts=True,
-                              layer=moe_layer)
+                              layer=layer)
     elif kind["ffn"] == "ffn":
         x = L.ffn(p["ffn"], x, spec, rt, rules)
-    return x, (new_cache or None), routed
+    return x, (new_cache if cache is not None else None), routed
 
 
 def _remat(fn, rt: RuntimeCfg):
     if rt.remat == "full":
         return jax.checkpoint(fn, prevent_cse=False)
-    if rt.remat == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            prevent_cse=False)
     return fn
 
 
@@ -273,30 +260,13 @@ def loss_fn(params: dict, batch: dict, spec, rt: RuntimeCfg,
     logits = forward(params, batch["tokens"], spec, rt, rules,
                      frames=batch.get("frames"), vision=batch.get("vision"))
     with jax.named_scope("loss"):
-        return _cross_entropy(logits, batch["labels"], rt)
+        return _cross_entropy(logits, batch["labels"])
 
 
-def _cross_entropy(logits, labels, rt: RuntimeCfg) -> jax.Array:
+def _cross_entropy(logits, labels) -> jax.Array:
     """Mean token cross-entropy of ``logits`` against ``labels``."""
     if logits.shape[1] != labels.shape[1]:       # VLM: vision positions unlabeled
         logits = logits[:, -labels.shape[1]:]
-    s = labels.shape[1]
-    if rt.loss_chunk and s % rt.loss_chunk == 0 and s > rt.loss_chunk:
-        # scan the CE over sequence chunks: the [B, chunk, V] fp32
-        # working set replaces the full [B, S, V] materialization
-        nc = s // rt.loss_chunk
-        lc = logits.reshape(logits.shape[0], nc, rt.loss_chunk, -1)             .transpose(1, 0, 2, 3)
-        yc = labels.reshape(labels.shape[0], nc, rt.loss_chunk)             .transpose(1, 0, 2)
-
-        def body(acc, inp):
-            lg, yy = inp
-            lgf = lg.astype(jnp.float32)
-            lse = jax.nn.logsumexp(lgf, axis=-1)
-            gold = jnp.take_along_axis(lgf, yy[..., None], axis=-1)[..., 0]
-            return acc + jnp.sum(lse - gold), None
-
-        tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (lc, yc))
-        return tot / (labels.shape[0] * s)
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
     gold = jnp.take_along_axis(logits.astype(jnp.float32),
                                labels[..., None], axis=-1)[..., 0]
@@ -309,41 +279,27 @@ def _cross_entropy(logits, labels, rt: RuntimeCfg) -> jax.Array:
 
 
 def _slot_cache(spec, rt, kind: dict, batch: int, kv_len: int) -> dict:
-    cdt = dt(rt.compute_dtype)
-    c: dict = {}
     if kind["mixer"] == "attn":
-        if spec.block == "mla":                  # time major: [T, B, ...]
-            m = spec.mla
-            c["attn"] = {"ckv": jnp.zeros((kv_len, batch, m.kv_lora), cdt),
-                         "kr": jnp.zeros((kv_len, batch, m.rope_dim), cdt),
-                         "pos": jnp.zeros((), jnp.int32)}
-        else:
-            nkv, dh = max(1, spec.n_kv_heads), spec.head_dim
-            klen = min(kv_len, spec.window) if kind["window"] else kv_len
-            c["attn"] = {"k": jnp.zeros((batch, klen, nkv, dh), cdt),
-                         "v": jnp.zeros((batch, klen, nkv, dh), cdt),
-                         "pos": jnp.zeros((), jnp.int32)}
+        c = {"attn": L.init_mla_cache(spec, rt, batch, kv_len)
+             if spec.block == "mla"
+             else L.init_gqa_cache(spec, rt, batch, kv_len, kind["window"])}
     elif kind["mixer"] == "mamba":
-        ss = spec.ssm
-        din = ss.expand * spec.d_model
-        c["mamba"] = {"conv": jnp.zeros((batch, 3, din), cdt),
-                      "ssm": jnp.zeros((batch, din, ss.d_state), jnp.float32)}
+        c = {"mamba": L.init_mamba_cache(spec, rt, batch)}
     else:
-        nh, dh = spec.n_heads, spec.head_dim
-        c["rwkv"] = {"wkv": jnp.zeros((batch, nh, dh, dh), jnp.float32),
-                     "shift_tm": jnp.zeros((batch, spec.d_model), cdt),
-                     "shift_cm": jnp.zeros((batch, spec.d_model), cdt)}
+        c = {"rwkv": L.init_rwkv6_cache(spec, rt, batch)}
     if spec.encoder_layers:
-        nkv, dh = max(1, spec.n_kv_heads), spec.head_dim
-        c["cross"] = {"k": jnp.zeros((batch, spec.enc_seq, nkv, dh), cdt),
-                      "v": jnp.zeros((batch, spec.enc_seq, nkv, dh), cdt)}
+        c["cross"] = L.init_gqa_cache(spec, rt, batch, spec.enc_seq)
     return c
 
 
 def init_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
+    """The decode cache: ``pos``, the next position to write (one int32
+    for every layer and row); each prefix layer's cache; and each
+    scanned slot's caches stacked over its layers."""
     prefix_n, period = layer_pattern(spec)
     n_rep = (spec.n_layers - prefix_n) // period if period else 0
     cache: dict = {
+        "pos": jnp.zeros((), jnp.int32),
         "prefix": [_slot_cache(spec, rt, _slot_kind(spec, l), batch, kv_len)
                    for l in range(prefix_n)],
         "slots": [],
@@ -356,40 +312,33 @@ def init_cache(spec, rt: RuntimeCfg, batch: int, kv_len: int) -> dict:
     return cache
 
 
-def _in_place(kind: dict) -> bool:
-    """Whether a slot's layers write their attention cache by position
-    (full attention, GQA or MLA), so that ``decode_step`` can carry the
-    slot's cache stack through the layer scan.  Sliding-window ring
-    caches, mamba and rwkv states are rewritten whole."""
-    return kind["mixer"] == "attn" and kind["window"] is None
-
-
 def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
                 rules: Optional[AxisRules] = None, *,
                 routed: bool = False) -> tuple:
-    """One decode step: tokens [B, 1] -> (logits [B,1,V], new cache).
-    With ``routed`` also the tokens of all B rows routed to each held
+    """One decode step: tokens [B, S] -> (logits [B,S,V], new cache), the
+    tokens written from the cache's ``pos``, which comes back advanced by
+    S.  With ``routed`` also the tokens of all B rows routed to each held
     expert of each MoE layer, in layer order: [MoE layers, held] int32
     (None for a model without MoE layers).
 
-    A scanned slot whose layers write their attention cache by position
-    carries that cache's stack through the scan, and each layer writes
-    its new positions into it; with the cache donated, as ``Engine``
-    does, the whole cache is then updated in place.  Other caches go
-    through the scan as its inputs and outputs."""
+    Each scanned slot's whole cache stack is carried through the layer
+    scan, and each layer writes its own part of it (its new positions,
+    or its whole entry for a ring window, mamba or rwkv state); with the
+    cache donated, as ``Engine`` does, the whole cache is updated in
+    place."""
     with jax.named_scope("embed"):
         x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
+    pos = cache["pos"]
     prefix_n, period = layer_pattern(spec)
-    new_cache = {"prefix": [], "slots": []}
+    new_cache = {"pos": pos + tokens.shape[1], "prefix": [],
+                 "slots": cache["slots"]}
     counts = []            # (layer index, [held]) of each MoE layer
-    li = 0
-    for p, c in zip(params["prefix"], cache["prefix"]):
-        kind = _slot_kind(spec, li)
-        x, nc, r = _apply_slot(p, x, spec, rt, rules, kind, cache=c)
+    for li, (p, c) in enumerate(zip(params["prefix"], cache["prefix"])):
+        x, nc, r = _apply_slot(p, x, spec, rt, rules, _slot_kind(spec, li),
+                               cache=c, pos=pos)
         new_cache["prefix"].append(nc)
         if r is not None:
             counts.append((li, r))
-        li += 1
 
     n_rep = (spec.n_layers - prefix_n) // period
     kinds = [_slot_kind(spec, prefix_n + s) for s in range(period)]
@@ -401,46 +350,30 @@ def decode_step(params: dict, cache: dict, tokens, spec, rt: RuntimeCfg,
             moe = dict(ps[s]["moe"])
             experts[s] = {k: moe.pop(k) for k in L.EXPERT_WEIGHTS}
             ps[s] = {**ps[s], "moe": moe}
-    stacks = [c.get("attn") if _in_place(k) else None
-              for c, k in zip(cache["slots"], kinds)]
-    rest = [{k: v for k, v in c.items() if st is None or k != "attn"}
-            for c, st in zip(cache["slots"], stacks)]
 
     def group(carry, xs):
         """Layers ``prefix_n + i * period + s`` for each slot ``s``."""
-        (h, stacks), (pcs, ccs, i) = carry, xs[:3]
-        cross_p = xs[3] if spec.encoder_layers else None
-        stacks, ncs, rs = list(stacks), [], []
+        (h, caches, i), pcs = carry, xs[0]
+        cross_p = xs[1] if spec.encoder_layers else None
+        caches, rs = list(caches), []
         for s in range(period):
-            pc, cc = pcs[s], ccs[s]
+            pc = pcs[s]
             if experts[s] is not None:
                 pc = {**pc, "moe": {**pc["moe"], **experts[s]}}
-            if stacks[s] is not None:
-                cc = {**cc, "attn": stacks[s]}
-            h, nc, r = _apply_slot(
-                pc, h, spec, rt, rules, kinds[s], cache=cc, cross_p=cross_p,
-                cross_cache=cc.get("cross"),
-                moe_layer=None if experts[s] is None else i,
-                layer=None if stacks[s] is None else i)
-            if stacks[s] is not None:
-                nc = dict(nc)
-                stacks[s] = nc.pop("attn")
-            ncs.append(nc)
+            h, caches[s], r = _apply_slot(
+                pc, h, spec, rt, rules, kinds[s], cache=caches[s], pos=pos,
+                cross_p=cross_p, layer=i)
             rs.append(r)
-        return (h, stacks), (ncs, rs)
+        return (h, caches, i + 1), rs
 
     if n_rep:
-        scanned = (ps, rest, jnp.arange(n_rep)) + \
-            ((params["cross"],) if spec.encoder_layers else ())
-        (x, stacks), (ncs, rs) = jax.lax.scan(group, (x, stacks), scanned)
-        new_cache["slots"] = [nc if st is None else {"attn": st, **nc}
-                              for nc, st in zip(ncs, stacks)]
+        scanned = (ps, params["cross"]) if spec.encoder_layers else (ps,)
+        (x, new_cache["slots"], _), rs = jax.lax.scan(
+            group, (x, cache["slots"], jnp.int32(0)), scanned)
         for s, r in enumerate(rs):
             if r is not None:
                 counts += [(prefix_n + s + i * period, r[i])
                            for i in range(n_rep)]
-    else:
-        new_cache["slots"] = cache["slots"]
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(params["ln_f"], x)

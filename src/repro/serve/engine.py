@@ -1,10 +1,12 @@
-"""Batched serving engine: prefill + decode with a static KV budget.
+"""Batched serving engine: slot-based batching over the decode step with
+a static KV budget.
 
 ``serve_step`` is the unit the dry-run lowers (one token for the whole
 batch against a seq_len cache).  The engine adds simple continuous
-batching on top: finished sequences release their slot, queued requests
-claim it, and the cache row is reset in place — the slot-level pattern
-behind production LLM servers, on a static-shape substrate XLA likes.
+batching on top: finished sequences release their slot and queued
+requests claim it.  The cache holds one position for all rows
+(``lm.init_cache``) and a claimed row is not reset, so only requests
+admitted together are served as a fresh cache would serve them.
 
 Admission is host-only bookkeeping: it places queued requests in free
 slots and touches no device buffer.  Prompts reach the device through
@@ -73,15 +75,6 @@ def make_serve_step(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None,
         return lm.decode_step(params, cache, tokens, spec, rt, rules,
                               routed=routed)
     return serve_step
-
-
-def make_prefill(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None):
-    def prefill(params, tokens):
-        """Full-batch prefill -> last-position logits (cache fill is done
-        token-by-token via serve_step in this reference engine)."""
-        logits = lm.forward(params, tokens, spec, rt, rules)
-        return logits[:, -1:]
-    return prefill
 
 
 class Engine:

@@ -1,12 +1,12 @@
 """A plain decode step, and a comparison of ``repro.serve.Engine`` with it.
 
 ``plain_decode_step`` runs the layers one after another in Python, each
-with its own cache, the way the model reads on paper: no layer scan, no
-cache stack written in place, no donation.  ``engine_matches_plain``
-steps an ``Engine`` (which donates its cache and writes each layer's new
-positions into the stack) over a few requests, then feeds the plain step
-the same token batches from a fresh cache, and compares the logits of
-every step and the caches at the end.
+with its own cache and all at the cache's one position, the way the
+model reads on paper: no layer scan, no cache stack written in place, no
+donation.  ``engine_matches_plain`` steps an ``Engine`` (which donates
+its cache and writes each layer's part of the stack) over a few
+requests, then feeds the plain step the same token batches from a fresh
+cache, and compares the logits of every step and the caches at the end.
 """
 import functools
 
@@ -23,7 +23,9 @@ def plain_decode_step(params, cache, tokens, spec, rt):
     """tokens [B, 1] -> (logits [B, 1, V], new cache), layer by layer."""
     x = params["embed"].value.astype(dt(rt.compute_dtype))[tokens]
     prefix_n, period = lm.layer_pattern(spec)
-    new = {"prefix": [], "slots": [[] for _ in range(period)]}
+    pos = cache["pos"]
+    new = {"pos": pos + tokens.shape[1], "prefix": [],
+           "slots": [[] for _ in range(period)]}
     for layer in range(spec.n_layers):
         if layer < prefix_n:
             p, c = params["prefix"][layer], cache["prefix"][layer]
@@ -35,7 +37,7 @@ def plain_decode_step(params, cache, tokens, spec, rt):
                  else None)
         x, nc, _ = lm._apply_slot(p, x, spec, rt, None,
                                   lm._slot_kind(spec, layer), cache=c,
-                                  cross_p=cross, cross_cache=c.get("cross"))
+                                  pos=pos, cross_p=cross)
         if layer < prefix_n:
             new["prefix"].append(nc)
         else:

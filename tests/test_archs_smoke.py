@@ -3,11 +3,13 @@ forward/train step on CPU; shapes + finiteness asserted (assignment
 requirement (f))."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get
 from repro.models import (RuntimeCfg, decode_step, init_cache, init_params,
                           lm, loss_fn)
+from repro.serve import Engine, Request
 
 from plain_decode import engine_matches_plain
 
@@ -64,18 +66,39 @@ def test_smoke_decode_step(name):
 
 @pytest.mark.parametrize("name", ARCHS)
 def test_engine_decode_matches_plain_decode(name):
-    """Decode steps through the Engine, which donates its cache, carries
-    full-attention caches (GQA, MLA) through the layer scan and writes
-    each layer's new position into the stack in place, and passes ring,
-    mamba, rwkv and cross-attention caches through the scan as before,
-    give each step's logits and the final caches of a plain decode that
-    runs the layers one by one on caches of their own."""
+    """Decode steps through the Engine, which donates its cache and
+    carries each scanned slot's cache stack through the layer scan (full
+    attention writes its new positions into the stack, ring windows,
+    mamba and rwkv states rewrite their layer's entry, cross-attention
+    reads its own), give each step's logits and the final caches of a
+    plain decode that runs the layers one by one on caches of their
+    own."""
     spec = get(name).smoke
     params = init_params(spec, RT_F32, jax.random.PRNGKey(0))
     _, _, steps = engine_matches_plain(
         spec, RT_F32, params, [[3, 1, 4], [1, 5, 9, 2, 6]], max_new=3,
         kv_len=64, rel=1e-5)
     assert steps == 7
+
+
+@pytest.mark.parametrize("name", ARCHS)
+def test_cache_holds_one_position(name):
+    """The decode cache holds one position, at its top, for every layer
+    and row; after k Engine steps it reads k."""
+    spec = get(name).smoke
+    cache = init_cache(spec, RT, 2, 64)
+    named = [p for p, _ in jax.tree.leaves_with_path(cache)
+             if any(getattr(k, "key", None) == "pos" for k in p)]
+    assert named == [(jax.tree_util.DictKey("pos"),)]
+    assert cache["pos"].shape == () and cache["pos"].dtype == jnp.int32
+    eng = Engine(spec, RT, init_params(spec, RT, jax.random.PRNGKey(0)),
+                 batch_slots=2, kv_len=64)
+    for i, n in enumerate((2, 4)):
+        eng.submit(Request(rid=i, prompt=np.arange(1, n + 1, dtype=np.int32),
+                           max_new=2))
+    eng.run(max_steps=64)
+    assert eng.n_steps == 5
+    assert int(eng.cache["pos"]) == eng.n_steps
 
 
 def test_decode_matches_forward_on_a_periodic_stack():

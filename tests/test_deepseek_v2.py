@@ -234,7 +234,8 @@ def test_engine_decode_matches_plain_decode():
     the stack they carry.  Each step's logits and the final cache equal
     those of a plain decode that runs the layers one by one, and read
     by position through the [B, T, R] view of the time-major cache,
-    every row holds exactly the positions the steps wrote."""
+    every row holds exactly the positions the steps wrote; the cache's
+    one position reads the number of steps."""
     params, _ = _weights(SPEC)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, SPEC.vocab, size=n) for n in (4, 7, 2)]
@@ -250,4 +251,4 @@ def test_engine_decode_matches_plain_decode():
         for a in (ckv, kr):                       # [layers, B, T, R]
             assert (np.abs(a[:, :, :steps]).max(-1) > 0).all()
             assert not a[:, :, steps:].any()
-        assert (np.asarray(c["pos"]) == steps).all()
+    assert int(got["pos"]) == steps
