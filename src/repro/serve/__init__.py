@@ -15,7 +15,7 @@ so capacity planning never needs a device:
 from repro.api import Job, Phase
 from repro.core.serving import DecodeSeries, JobResult, PhaseResult
 
-from .engine import Engine, Request, make_serve_step
+from .engine import Engine, Request, greedy_step, make_serve_step
 
-__all__ = ["Engine", "Request", "make_serve_step",
+__all__ = ["Engine", "Request", "greedy_step", "make_serve_step",
            "Job", "Phase", "JobResult", "PhaseResult", "DecodeSeries"]

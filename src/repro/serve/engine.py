@@ -13,20 +13,39 @@ slots and touches no device buffer.  Prompts reach the device through
 ``run``'s host-built token batch, one position per step (prefill by
 decode), in the same ``step_fn`` call that decodes the other rows.
 
+The engine's step (``greedy_step`` around ``make_serve_step``) takes the
+argmax on the device: a decoding row feeds the previous step's token,
+which never leaves the device, so the host can dispatch step k before it
+reads step k-1.  A request ends only at ``max_new``, so which request
+holds which row at which step, and whether the row feeds a prompt token
+or its own last output, is fixed at admission: ``run`` frees a row once
+its last step is dispatched (and admits into it before the next step),
+not when that step is read.  Each ``run`` iteration builds the feed of
+step k, dispatches step k, then reads step k-1's tokens and appends them
+to their requests; the step left in flight is read by the next
+iteration, or the next ``run`` call.  Where the step just dispatched is
+the last the current work needs (no row has a further step and the
+queue is empty) it is read in the same iteration, so ``run`` returns
+every finished request with nothing left in flight.
+
 Instruments (``repro.obs``; always on, a few µs a step).  Spans, which
 reach the JAX profiler's trace whenever it records: ``engine.admit``
 (args ``requests``, ``prompt_tokens``, ``rids``) when a request is
 admitted, and ``engine.step`` (``step``, ``rows``) with its children
 ``engine.feed`` (the token batch built on the host), ``engine.dispatch``
-(the ``step_fn`` call) and ``engine.sample`` (argmax and its host read:
-the host waits for the device there).  Histograms of ordered samples:
+(the ``step_fn`` call) and ``engine.sample`` (arg ``step``, the step it
+reads: the host read of that step's tokens, where the host waits for the
+device), one per step.  Histograms of ordered samples:
 ``engine.admit_s`` per admission, ``engine.queue_wait_s`` per request
 (submit to the end of its own admission), ``engine.host_gap_s`` per step
-after the engine's first (end of the previous ``engine.sample`` to this
-``engine.dispatch``, less the admission time between them),
-``engine.useful_rows`` per step (rows that appended an output token) and
-``engine.ttft_s`` per request (submit to the host's read of its first
-token).  Counters: ``engine.steps``, ``engine.requests_admitted``,
+after the engine's first (end of the engine's previous iteration, its
+read or, where it read nothing, its dispatch, to this dispatch, less the
+admission time between them), ``engine.useful_rows`` per step (rows that
+appended an output token) and ``engine.ttft_s`` per request (submit to
+the host's read of its first token).  Counters: ``engine.steps``,
+``engine.steps_ahead`` (the steps dispatched while the previous step's
+tokens were still unread: ``engine.steps`` less one for each time the
+engine ran out of work), ``engine.requests_admitted``,
 ``engine.prompt_tokens_admitted``, ``engine.tokens_out``,
 ``engine.rows_prefill`` and ``engine.cache_donations``, the steps that
 consumed the cache they were given and so wrote its new positions in
@@ -36,7 +55,7 @@ backend can donate).  Each request keeps its own times
 (``t_submit``, ``t_admit``, ``t_first``; perf_counter) under its ``rid``.
 For a model with MoE layers the step also counts, on the device, the
 tokens of all rows routed to each held expert of each MoE layer; the
-host reads them with the sampled tokens, in one read, into the histogram
+host reads them with the step's tokens, in one read, into the histogram
 ``moe.expert_tokens`` (their mean, tokens per held expert per layer, one
 sample per step) and the counter ``moe.routed_tokens`` (their sum).
 """
@@ -77,6 +96,20 @@ def make_serve_step(spec, rt: RuntimeCfg, rules: Optional[AxisRules] = None,
     return serve_step
 
 
+def greedy_step(step):
+    """Greedy sampling inside the step: (params, cache, host, prev) ->
+    (tokens, cache, ...) around ``step`` ((params, cache, tokens [B, 1])
+    -> (logits, cache, ...)).  Row i feeds ``host[i]``, or ``prev[i]``
+    (the previous step's token, left on the device) where ``host[i]`` is
+    -1, and gets the argmax of its logits, all as int32 [B]."""
+    def serve_step(params, cache, host, prev):
+        tokens = jnp.where(host >= 0, host, prev)[:, None]
+        logits, cache, *rest = step(params, cache, tokens)
+        return (jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32), cache,
+                *rest)
+    return serve_step
+
+
 class Engine:
     """Slot-based continuous batching over ``serve_step``."""
 
@@ -87,14 +120,16 @@ class Engine:
         self.slots: list[Optional[Request]] = [None] * batch_slots
         self.cache = lm.init_cache(spec, rt, batch_slots, kv_len)
         self.routed = spec.moe is not None
+        self.serve_step = greedy_step(make_serve_step(spec, rt, rules,
+                                                      routed=self.routed))
         # the step consumes the cache it is given and writes the new
         # positions into it in place (``lm.decode_step``)
-        self.step_fn = jax.jit(make_serve_step(spec, rt, rules,
-                                               routed=self.routed),
-                               donate_argnums=(1,))
+        self.step_fn = jax.jit(self.serve_step, donate_argnums=(1,))
         self.queue: list[Request] = []
         self.n_steps = 0
-        self._sampled: Optional[float] = None   # end of the last sample
+        self._tokens = jnp.zeros((batch_slots,), jnp.int32)  # last argmax
+        self._pending = None        # the dispatched step not yet read
+        self._gap_from: Optional[float] = None  # last read, or dispatch
         self._admit_s = 0.0                     # admission time since
         runtime_hooks()
 
@@ -117,7 +152,7 @@ class Engine:
                 if s is None and self.queue:
                     req = self.queue.pop(0)
                     self.slots[i] = req
-                    req._fed = 0
+                    req._fed = req._due = 0
                     req.t_admit = time.perf_counter()
                     metrics.histogram("engine.queue_wait_s").observe(
                         req.t_admit - req.t_submit)
@@ -126,71 +161,89 @@ class Engine:
         metrics.counter("engine.requests_admitted").inc(len(take))
         metrics.counter("engine.prompt_tokens_admitted").inc(n_tok)
 
+    def _busy(self) -> bool:
+        """A row has a step to come, or a request waits for a row."""
+        return bool(self.queue) or any(s is not None for s in self.slots)
+
+    def _read(self, step: int, tokens, routed: list, rows: list
+              ) -> list[Request]:
+        """Read a dispatched step's tokens (and routed counts) and append
+        them to its rows' requests; returns the requests it finished."""
+        with span("engine.sample", step=step):
+            tokens, routed = jax.device_get((tokens, routed))
+        self._gap_from = t_read = time.perf_counter()
+        if routed:
+            metrics.histogram("moe.expert_tokens").observe(
+                float(routed[0].mean()))
+            metrics.counter("moe.routed_tokens").inc(int(routed[0].sum()))
+        finished, useful = [], 0
+        for i, req, emits, last in rows:
+            if emits:
+                req.out.append(int(tokens[i]))
+                useful += 1
+                if len(req.out) == 1:
+                    req.t_first = t_read
+                    metrics.histogram("engine.ttft_s").observe(
+                        req.t_first - req.t_submit)
+            if last:
+                req.done = True
+                finished.append(req)
+        metrics.counter("engine.tokens_out").inc(useful)
+        metrics.histogram("engine.useful_rows").observe(useful)
+        return finished
+
     def run(self, max_steps: int = 64) -> list[Request]:
         """Greedy-decode all queued requests; returns finished requests."""
         finished: list[Request] = []
         self._admit()
         for _ in range(max_steps):
-            if all(s is None for s in self.slots) and not self.queue:
+            if not self._busy():
                 break
             with span("engine.step", step=self.n_steps) as st:
                 with span("engine.feed"):
-                    # build the batched token: prompts feed first, then argmax
-                    tok_host = np.zeros((len(self.slots), 1), np.int32)
-                    live = prefill = 0
+                    # the batched token: prompts feed first, then -1 marks
+                    # a row that feeds its own last output (on the device);
+                    # a row whose last step this is is freed
+                    host = np.zeros(len(self.slots), np.int32)
+                    rows, prefill = [], 0
                     for i, req in enumerate(self.slots):
                         if req is None:
                             continue
-                        live += 1
                         if req._fed < len(req.prompt):
-                            tok_host[i, 0] = req.prompt[req._fed]
+                            host[i] = req.prompt[req._fed]
                             req._fed += 1
                             prefill += 1
-                        elif req.out:
-                            tok_host[i, 0] = req.out[-1]
-                st.set(rows=live)
+                        elif req._due:
+                            host[i] = -1
+                        emits = req._fed >= len(req.prompt)
+                        req._due += emits
+                        last = req._due >= req.max_new
+                        rows.append((i, req, emits, last))
+                        if last:
+                            self.slots[i] = None
+                st.set(rows=len(rows))
                 t_dispatch = time.perf_counter()
                 with span("engine.dispatch"):
                     given = jax.tree.leaves(self.cache)[0]
-                    out = self.step_fn(self.params, self.cache,
-                                       jnp.asarray(tok_host))
-                    logits, self.cache = out[0], out[1]
+                    self._tokens, self.cache, *routed = self.step_fn(
+                        self.params, self.cache, host, self._tokens)
                 donated = given.is_deleted()
-                with span("engine.sample"):
-                    nxt = jnp.argmax(logits[:, 0], axis=-1)
-                    if self.routed:
-                        nxt, routed = jax.device_get((nxt, out[2]))
-                    else:
-                        nxt = np.asarray(nxt)
-                t_sampled = time.perf_counter()
-                if self.routed:
-                    metrics.histogram("moe.expert_tokens").observe(
-                        float(routed.mean()))
-                    metrics.counter("moe.routed_tokens").inc(int(routed.sum()))
-                useful = 0
-                for i, req in enumerate(self.slots):
-                    if req is None:
-                        continue
-                    if req._fed >= len(req.prompt):
-                        req.out.append(int(nxt[i]))
-                        useful += 1
-                        if len(req.out) == 1:
-                            req.t_first = t_sampled
-                            metrics.histogram("engine.ttft_s").observe(
-                                req.t_first - req.t_submit)
-                    if len(req.out) >= req.max_new:
-                        req.done = True
-                        finished.append(req)
-                        self.slots[i] = None
-            if self._sampled is not None:
-                metrics.histogram("engine.host_gap_s").observe(
-                    t_dispatch - self._sampled - self._admit_s)
-            self._sampled, self._admit_s = t_sampled, 0.0
-            self.n_steps += 1
-            metrics.counter("engine.steps").inc()
-            metrics.counter("engine.cache_donations").inc(int(donated))
-            metrics.counter("engine.rows_prefill").inc(prefill)
-            metrics.counter("engine.tokens_out").inc(useful)
-            metrics.histogram("engine.useful_rows").observe(useful)
+                ahead, self._pending = self._pending, (
+                    self.n_steps, self._tokens, routed, rows)
+                if self._gap_from is not None:
+                    metrics.histogram("engine.host_gap_s").observe(
+                        t_dispatch - self._gap_from - self._admit_s)
+                self._gap_from, self._admit_s = time.perf_counter(), 0.0
+                self.n_steps += 1
+                metrics.counter("engine.steps").inc()
+                metrics.counter("engine.steps_ahead").inc(
+                    int(ahead is not None))
+                metrics.counter("engine.cache_donations").inc(int(donated))
+                metrics.counter("engine.rows_prefill").inc(prefill)
+                if ahead is not None:
+                    finished += self._read(*ahead)
+                if not self._busy():          # the last step this work needs
+                    finished += self._read(*self._pending)
+                    self._pending = None
             self._admit()
         return finished

@@ -3,10 +3,12 @@
 ``plain_decode_step`` runs the layers one after another in Python, each
 with its own cache and all at the cache's one position, the way the
 model reads on paper: no layer scan, no cache stack written in place, no
-donation.  ``engine_matches_plain`` steps an ``Engine`` (which donates
-its cache and writes each layer's part of the stack) over a few
-requests, then feeds the plain step the same token batches from a fresh
-cache, and compares the logits of every step and the caches at the end.
+donation.  ``engine_matches_plain`` steps an ``Engine`` over a few
+requests, its greedy step (``greedy_step``) run around a jitted
+``make_serve_step`` that donates its cache and writes each layer's part
+of the stack, then feeds the plain step the same token batches from a
+fresh cache, and compares the logits of every step and the caches at the
+end.
 """
 import functools
 
@@ -16,7 +18,7 @@ import numpy as np
 
 from repro.models import layers as L, lm
 from repro.models.common import dt
-from repro.serve import Engine, Request
+from repro.serve import Engine, Request, greedy_step, make_serve_step
 
 
 def plain_decode_step(params, cache, tokens, spec, rt):
@@ -61,14 +63,16 @@ def engine_matches_plain(spec, rt, params, prompts, max_new: int,
     plain cache, the number of steps)."""
     eng = Engine(spec, rt, params, batch_slots=len(prompts), kv_len=kv_len)
     first = jax.tree.leaves(eng.cache)
-    step, fed, seen = eng.step_fn, [], []
+    step = jax.jit(make_serve_step(spec, rt, routed=eng.routed),
+                   donate_argnums=(1,))
+    fed, seen = [], []
 
     def recording(params, cache, tokens):
         fed.append(np.asarray(tokens))
         out = step(params, cache, tokens)
         seen.append(np.asarray(out[0]))
         return out
-    eng.step_fn = recording
+    eng.step_fn = greedy_step(recording)
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=np.asarray(p, np.int32),
                            max_new=max_new))

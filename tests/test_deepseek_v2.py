@@ -30,7 +30,8 @@ from repro.configs import get  # noqa: E402
 from repro.core import MLASpec, ModelSpec, MoESpec  # noqa: E402
 from repro.models import RuntimeCfg, init_params, layers as L, lm  # noqa: E402
 from repro.models.common import Param  # noqa: E402
-from repro.serve import Engine, Request  # noqa: E402
+from repro.serve import (Engine, Request, greedy_step,  # noqa: E402
+                         make_serve_step)
 
 from plain_decode import engine_matches_plain  # noqa: E402
 
@@ -71,13 +72,15 @@ def _served_logits(spec, params, prompts, max_new):
     """Engine.run over one wave; each request's logits at every position
     it fed (prompt then outputs), from the step's own output."""
     eng = Engine(spec, F32, params, batch_slots=len(prompts), kv_len=64)
-    step, seen = eng.step_fn, []
+    step = jax.jit(make_serve_step(spec, F32, routed=True),
+                   donate_argnums=(1,))
+    seen = []
 
     def recording(*a):
         out = step(*a)
         seen.append(np.asarray(out[0][:, 0]))
         return out
-    eng.step_fn = recording
+    eng.step_fn = greedy_step(recording)
     reqs = [Request(rid=i, prompt=p, max_new=max_new)
             for i, p in enumerate(prompts)]
     for r in reqs:

@@ -189,6 +189,8 @@ def test_engine_instruments_two_slots():
     assert c("engine.prompt_tokens_admitted").value == 9
     assert c("engine.rows_prefill").value == 9
     assert c("engine.cache_donations").value == steps   # the CPU donates
+    # each step after the first is dispatched before its predecessor is read
+    assert c("engine.steps_ahead").value == steps - 1
     assert all(r.t_submit <= r.t_admit <= r.t_first for r in reqs)
     # each step's phases are its children; admissions carry their rids
     step_ids = {e.id for e in prof.events if e.name == "engine.step"}
@@ -196,6 +198,13 @@ def test_engine_instruments_two_slots():
     for name in ("engine.feed", "engine.dispatch", "engine.sample"):
         kids = [e for e in prof.events if e.name == name]
         assert len(kids) == steps and all(k.parent in step_ids for k in kids)
+    # each step is read once, in order, inside the step after it (the last
+    # inside its own step)
+    reads = [e for e in prof.events if e.name == "engine.sample"]
+    by_id = {e.id: e.args["step"] for e in prof.events
+             if e.name == "engine.step"}
+    assert [e.args["step"] for e in reads] == list(range(steps))
+    assert [by_id[e.parent] for e in reads] == [*range(1, steps), steps - 1]
     admits = [e for e in prof.events if e.name == "engine.admit"]
     assert sorted(i for a in admits for i in a.args["rids"]) == [0, 1, 2]
     assert [a.args["prompt_tokens"] for a in admits] == [6, 3]
@@ -204,13 +213,13 @@ def test_engine_instruments_two_slots():
 def test_engine_counts_only_consumed_caches():
     """``engine.cache_donations`` counts the steps that consumed the
     cache they were given: none where the step does not donate."""
-    from repro.serve import Engine, Request, make_serve_step
+    from repro.serve import Engine, Request
     spec = ModelSpec(name="m", n_layers=2, d_model=64, n_heads=4,
                      n_kv_heads=2, d_ff=128, vocab=256)
     rt = RuntimeCfg(attention_impl="naive")
     eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
                  batch_slots=2, kv_len=16)
-    eng.step_fn = jax.jit(make_serve_step(spec, rt))
+    eng.step_fn = jax.jit(eng.serve_step)     # the engine's, not donating
     eng.submit(Request(rid=0, prompt=np.array([1, 2]), max_new=2))
     eng.run(max_steps=8)
     c = metrics.counter
@@ -241,6 +250,122 @@ def test_engine_admit_makes_no_device_transfer():
     assert c("engine.prompt_tokens_admitted").value == sum(lens[:8])
     assert h("engine.admit_s").count == 1
     assert h("engine.queue_wait_s").count == 8
+
+
+# one wave of mixed prompt (1-30) and output (1-20) lengths, one row each
+WAVE = [(1, 20), (30, 1), (7, 13), (16, 5), (3, 9), (24, 17), (12, 12)]
+
+
+def _wave(spec):
+    from repro.serve import Request
+    rng = np.random.default_rng(5)
+    return [Request(rid=i, max_new=o, prompt=rng.integers(
+        1, spec.vocab, p).astype(np.int32)) for i, (p, o) in enumerate(WAVE)]
+
+
+def _sequential(spec, rt, params, reqs, kv_len):
+    """The wave served one step after another: ``make_serve_step``, an
+    eager argmax of each step's logits read to the host, and each row's
+    token fed back to it.  Returns each request's tokens and each step's
+    routed counts (MoE)."""
+    from repro.models import lm
+    from repro.serve import make_serve_step
+    routed = spec.moe is not None
+    step = jax.jit(make_serve_step(spec, rt, routed=routed))
+    cache = lm.init_cache(spec, rt, len(reqs), kv_len)
+    outs, fed, counts = [[] for _ in reqs], [0] * len(reqs), []
+    while any(len(o) < r.max_new for o, r in zip(outs, reqs)):
+        live = [i for i, r in enumerate(reqs) if len(outs[i]) < r.max_new]
+        tok = np.zeros((len(reqs), 1), np.int32)
+        for i in live:
+            if fed[i] < len(reqs[i].prompt):
+                tok[i, 0] = reqs[i].prompt[fed[i]]
+                fed[i] += 1
+            else:
+                tok[i, 0] = outs[i][-1]
+        out = step(params, cache, jnp.asarray(tok))
+        cache = out[1]
+        nxt = np.asarray(jnp.argmax(out[0][:, 0], axis=-1))
+        if routed:
+            counts.append(np.asarray(out[2]))
+        for i in live:
+            if fed[i] >= len(reqs[i].prompt):
+                outs[i].append(int(nxt[i]))
+    return outs, counts
+
+
+@pytest.mark.parametrize("arch", ["granite-34b", "deepseek-v2-236b"])
+def test_engine_tokens_match_a_sequential_loop(arch):
+    """The engine, which samples on the device and dispatches each step
+    before it reads the one before, serves the tokens (and, for experts,
+    routes the counts) of the plain loop that reads every step first."""
+    from repro.serve import Engine
+    spec = get(arch).smoke
+    rt = RuntimeCfg(attention_impl="naive")
+    params = init_params(spec, rt, jax.random.PRNGKey(2))
+    eng = Engine(spec, rt, params, batch_slots=len(WAVE), kv_len=64)
+    reqs = _wave(spec)
+    for r in reqs:
+        eng.submit(r)
+    assert sorted(r.rid for r in eng.run(max_steps=64)) == list(
+        range(len(WAVE)))
+    want, counts = _sequential(spec, rt, params, _wave(spec), 64)
+    assert [r.out for r in reqs] == want
+    assert eng.n_steps == max(p + o for p, o in WAVE) - 1
+    h, c = metrics.histogram, metrics.counter
+    if spec.moe is not None:
+        assert eng.n_steps == len(counts)
+        assert h("moe.expert_tokens").newest() == [float(x.mean())
+                                                   for x in counts]
+        assert c("moe.routed_tokens").value == sum(int(x.sum())
+                                                   for x in counts)
+
+
+def test_engine_keeps_one_step_in_flight():
+    """Driven as the benchmark's serve loop drives it (one ``run`` call per
+    step, the cache reset between waves): one call per step, exactly one
+    step in flight after every call that leaves work, every step but each
+    wave's first dispatched ahead of its predecessor's read, none in flight
+    once a wave's last request is returned, and one compiled step."""
+    from repro.models import lm
+    from repro.serve import Engine
+    spec = get("granite-34b").smoke
+    rt = RuntimeCfg(attention_impl="naive")
+    eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
+                 batch_slots=len(WAVE), kv_len=64)
+    h, c = metrics.histogram, metrics.counter
+    steps = max(p + o for p, o in WAVE) - 1
+    for wave in range(2):
+        reqs, done = _wave(spec), 0
+        for r in reqs:
+            eng.submit(r)
+        for calls in range(1, steps + 3):
+            done += len(eng.run(max_steps=1))
+            if done == len(reqs):
+                break
+            assert eng._pending[0] == eng.n_steps - 1
+            assert h("engine.useful_rows").count == eng.n_steps - 1
+        assert eng._pending is None
+        assert h("engine.useful_rows").count == eng.n_steps
+        assert calls == steps and eng.n_steps == (wave + 1) * steps
+        assert c("engine.steps_ahead").value == eng.n_steps - (wave + 1)
+        assert all(r.done and len(r.out) == r.max_new for r in reqs)
+        eng.cache = None
+        eng.cache = lm.init_cache(spec, rt, len(WAVE), 64)
+    assert eng.step_fn._cache_size() == 1
+
+
+def test_engine_step_program_is_named_serve_step():
+    """The trace's readers find the decode step's device time by the
+    substring ``serve_step`` in its module's name."""
+    from repro.serve import Engine
+    spec = get("granite-34b").smoke
+    rt = RuntimeCfg(attention_impl="naive")
+    eng = Engine(spec, rt, init_params(spec, rt, jax.random.PRNGKey(0)),
+                 batch_slots=2, kv_len=16)
+    hlo = eng.step_fn.lower(eng.params, eng.cache, np.zeros(2, np.int32),
+                            eng._tokens).compile().as_text()
+    assert "serve_step" in hlo.splitlines()[0].split(",")[0]
 
 
 # --------------------------------------------------------------------------
